@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 import repro
+from repro import compile_cache
 from repro.core import params as params_mod
 from repro.core import polymul as pm
 from repro.core import schedule as sched
@@ -511,6 +512,7 @@ def main(argv=None) -> int:
                     help="kernel tile rows per grid step "
                          "(None = per-kernel default)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.ci_smoke:
         rec = run_ci_smoke(
             args.out, baseline_path=args.baseline, compiled=args.compiled

@@ -9,6 +9,9 @@ import time
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         latency_model,
         lm_step,

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 import repro
-from repro import api
+from repro import api, compile_cache
 from repro.serve.crypto_engine import PolymulEngine
 
 
@@ -292,6 +292,7 @@ def main(argv=None) -> int:
                     help="tracing overhead budget as a fraction "
                          "(--tracing-overhead)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.tracing_overhead:
         rec = tracing_overhead(
             args.n, args.t, args.v, batch=args.batch,

@@ -27,6 +27,7 @@ __version__ = "0.2.0"
 
 from repro.errors import (  # noqa: E402
     BackendFailedError,
+    CompileFailedError,
     DeadlineExceededError,
     EngineError,
     PlanError,
@@ -69,6 +70,7 @@ def verify_plan(pl, **kwargs):
 __all__ = [
     "BACKENDS",
     "BackendFailedError",
+    "CompileFailedError",
     "DeadlineExceededError",
     "EngineError",
     "QueueFullError",

@@ -21,7 +21,7 @@ Every traced value is abstracted by an :class:`AbsVal`:
 * a ``prov`` provenance tuple ``(prim, *operand AbsVals)`` recorded for
   comparison/arithmetic primitives so the Shoup/Barrett patterns and
   the conditional-subtract refinement can be matched *across* jaxpr
-  scopes (jnp ``where`` lands inside ``pjit("_where")`` sub-jaxprs, so
+  scopes (jnp ``where`` lands inside ``jit("_where")`` sub-jaxprs, so
   def-use matching by eqn within one scope would not see the compare).
 
 Soundness rule for the q-linear term: it survives only channel-

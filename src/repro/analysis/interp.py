@@ -1,6 +1,6 @@
 """Abstract interpreter over traced crypto jaxprs.
 
-Walks a ``ClosedJaxpr`` (recursing through ``pjit``, ``cond`` and
+Walks a ``ClosedJaxpr`` (recursing through ``jit``, ``cond`` and
 ``pallas_call`` bodies) propagating :class:`repro.analysis.domain.AbsVal`
 per value, and emits :class:`Finding` records for
 
@@ -51,7 +51,7 @@ _ALIGNED_VIEW_PRIMS = frozenset(
 _REINDEX_VIEW_PRIMS = frozenset({"slice", "rev", "transpose", "dynamic_slice", "gather"})
 _VIEW_PRIMS = _ALIGNED_VIEW_PRIMS | _REINDEX_VIEW_PRIMS
 
-_CALL_PRIMS = frozenset({"pjit", "closed_call", "core_call", "custom_jvp_call"})
+_CALL_PRIMS = frozenset({"jit", "closed_call", "core_call", "custom_jvp_call"})
 
 _CARR_CAP = 65536  # max elements for materialized concrete constant arrays
 

@@ -12,7 +12,7 @@ from typing import Any, Iterator, Optional, Tuple
 
 def subjaxprs(eqn: Any) -> Iterator[Tuple[str, Any]]:
     """Yield ``(label, jaxpr-like)`` for every sub-jaxpr of one eqn:
-    pjit/closed-call bodies, cond branches, pallas kernel bodies.  The
+    jit/closed-call bodies, cond branches, pallas kernel bodies.  The
     yielded object may be a ClosedJaxpr or a raw Jaxpr."""
     params = getattr(eqn, "params", None) or {}
     for key in ("jaxpr", "call_jaxpr"):
@@ -45,7 +45,7 @@ def iter_consts(
     jaxpr_like: Any, path: Tuple[str, ...] = ()
 ) -> Iterator[Tuple[Tuple[str, ...], Any]]:
     """Depth-first ``(path, const)`` over the closure constants of a
-    ClosedJaxpr and of every nested ClosedJaxpr (pjit bodies, cond
+    ClosedJaxpr and of every nested ClosedJaxpr (jit bodies, cond
     branches); pallas bodies are raw Jaxprs and carry no consts."""
     for const in getattr(jaxpr_like, "consts", ()) or ():
         yield path, const

@@ -15,9 +15,11 @@ Usage::
 
 Width dispatch (resolved once, inside :func:`plan`):
 
-* ``v <= 31``  — the int64 Pallas datapath (``jnp`` / ``pallas`` /
+* ``v <= 31``  — the int64 datapath (``jnp`` / ``pallas`` /
   ``pallas_fused`` / ``pallas_fused_e2e`` backends, radix-2 or
-  lane-aligned four-step schedules);
+  lane-aligned four-step schedules; ``"auto"`` is ``jnp``, and on a TPU
+  the Pallas backends are refused at plan time because Mosaic cannot
+  compile their int64 kernels);
 * ``31 < v <= 46`` — the digit-split wide datapath (the paper's t=4 /
   v=45 configuration, :mod:`repro.core.wide`), pure-jnp;
 * ``v > 46``   — the host Python-bigint oracle (exact for any width;
@@ -401,11 +403,9 @@ def _consts_for(params: ParenttParams, width: str) -> dict[str, Any]:
 
 def _resolve_backend(width: str, backend: str) -> str:
     if backend == "auto":
-        if width == "int64":
-            return ops_mod.auto_backend()
-        return "jnp" if width == "wide" else ORACLE_BACKEND
+        return ORACLE_BACKEND if width == "oracle" else "jnp"
     if width == "int64":
-        return validate_backend(backend)
+        return ops_mod.check_servable(validate_backend(backend))
     if width == "wide":
         if backend != "jnp":
             raise UnservableConfigError(
@@ -532,9 +532,12 @@ def plan(
     upload every table, and resolve all execution knobs into a frozen
     :class:`PlanConfig`.
 
-    ``backend="auto"`` picks the fused single-kernel Pallas path on TPU
-    and the pure-jnp reference elsewhere (for v <= 31); the wide and
-    oracle widths have exactly one datapath each.  ``schedule`` accepts
+    ``backend="auto"`` picks the pure-jnp datapath for v <= 46 and the
+    host oracle beyond; a Pallas backend asked for on a TPU raises
+    :class:`repro.errors.UnservableConfigError` (its int64 kernels do
+    not compile there, see :func:`repro.kernels.ops.check_servable`).
+
+    ``schedule`` accepts
     ``"auto"`` (lane-aligned four-step for n >= 256), ``"radix2"``,
     ``"four_step"``, ``"four_step:h"`` (asserts the hierarchical
     depth >= 2 chain, available from n = 8192) or an explicit
@@ -640,7 +643,7 @@ def plan_from_params(
     ``row_blk`` fields) into a :class:`Plan`."""
     width = width_for(params.v)
     if width == "int64":
-        backend = ops_mod.resolve_backend(params, backend)
+        backend = ops_mod.check_servable(ops_mod.resolve_backend(params, backend))
     else:
         backend = _resolve_backend(width, backend or "auto")
     spec = _resolve_spec(

@@ -33,6 +33,10 @@ future carries the error, it is never silently dropped:
 * :class:`BackendFailedError` — every dispatch attempt (bounded retry +
   backend degradation) failed; the last underlying exception rides in
   ``__cause__`` and the attribute fields say where it died.
+* :class:`CompileFailedError` — the plan's executable did not lower or
+  compile.  Not a transient fault: it is raised by
+  ``PolymulEngine.warmup`` and fails a bucket's requests without retry
+  or degradation.
 """
 from __future__ import annotations
 
@@ -162,3 +166,10 @@ class BackendFailedError(EngineError):
         super().__init__(message, request_seq=request_seq)
         self.backend = backend
         self.attempts = attempts
+
+
+class CompileFailedError(BackendFailedError):
+    """The executable for a plan did not lower or compile for this
+    device.  The compiler's exception rides in ``__cause__``.  Unlike
+    a dispatch fault it is deterministic, so the engine neither retries
+    it nor opens a breaker on it (``attempts`` is 0)."""

@@ -177,7 +177,7 @@ def _make_decompose_kernel(ch: rns_mod.ChannelDecompose, seg_count: int,
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "interpret"))
-def decompose_pallas(z, *, plan: rns_mod.RnsPlan, interpret: bool = True):
+def decompose_pallas(z, *, plan: rns_mod.RnsPlan, interpret: bool):
     """z: (rows, S) segments -> residues (t, rows).  One specialized
     pallas_call per RNS channel (= per hardware circuit)."""
     rows, S = z.shape
@@ -264,7 +264,7 @@ def _make_compose_kernel(plan: rns_mod.RnsPlan):
 
 @functools.partial(jax.jit, static_argnames=("plan", "interpret"))
 def compose_pallas(residues, *, plan: rns_mod.RnsPlan, qs=None, qi_tilde=None,
-                   star=None, q_limbs=None, interpret: bool = True):
+                   star=None, q_limbs=None, interpret: bool):
     """residues: (t, rows) -> limbs (rows, L) of the composed value mod q.
 
     The CRT table operands default to the plan's own device uploads;

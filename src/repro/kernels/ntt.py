@@ -680,7 +680,7 @@ def _chgrid_fs_spec(x):
 def ntt_channels_pallas(
     a, qs, fwd, eps=None, fwd_shoup=None, row_fwd=None, row_fwd_shoup=None,
     *, shifts=None, schedule: str = "radix2", lazy=None,
-    row_blk: int = DEFAULT_ROWS, interpret: bool = True,
+    row_blk: int = DEFAULT_ROWS, interpret: bool,
 ):
     """a: (t, rows, n) -> forward NTT per channel.  qs: (t,), fwd: (t, n);
     row_fwd: (t, n2, n1) twist-merged row tables (four_step only) or a
@@ -721,7 +721,7 @@ def ntt_channels_pallas(
 def intt_channels_pallas(
     a, qs, half, inv, eps=None, inv_shoup=None, row_inv=None, row_inv_shoup=None,
     *, shifts=None, schedule: str = "radix2", lazy=None,
-    row_blk: int = DEFAULT_ROWS, interpret: bool = True,
+    row_blk: int = DEFAULT_ROWS, interpret: bool,
 ):
     kind = getattr(schedule, "kind", schedule)
     row_inv = _as_level_tuple(row_inv)
@@ -758,7 +758,7 @@ def fused_polymul_pallas(
     a, b, qs, half, fwd, inv, eps=None, fwd_shoup=None, inv_shoup=None,
     row_fwd=None, row_inv=None, row_fwd_shoup=None, row_inv_shoup=None,
     *, shifts=None, schedule: str = "radix2", lazy=None,
-    row_blk: int = DEFAULT_ROWS, interpret: bool = True,
+    row_blk: int = DEFAULT_ROWS, interpret: bool,
 ):
     """(t, rows, n) x (t, rows, n) -> negacyclic products, fused cascade."""
     kind = getattr(schedule, "kind", schedule)
@@ -804,7 +804,7 @@ def fused_e2e_polymul_pallas(
     row_fwd=None, row_inv=None, row_fwd_shoup=None, row_inv_shoup=None,
     *, plan, schedule: str = "radix2", lazy=None,
     channel_grid: bool | None = None, row_blk: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """za, zb: (rows, n, S) base-2^v segment tiles -> (rows, n, L) limbs
     of the negacyclic products mod q: decompose -> NTT -> ⊙ -> iNTT ->

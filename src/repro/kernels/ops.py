@@ -35,9 +35,14 @@ with concrete values — per-call resolution here exists for the legacy
 entry points and degrades to validation when the value is already
 concrete.
 
-Pallas kernels run in interpret mode off-TPU and compiled mode on TPU.
-The ``"jnp"`` backend is also what the dry-run lowering uses on the
-512-device mesh, where interpret-mode python loops would bloat the HLO.
+Pallas kernels run in interpret mode off-TPU.  On a TPU none of them
+compiles yet: every Pallas datapath computes on int64, and Mosaic (the
+TPU's Pallas compiler) has no 64-bit vector layout.  So on a TPU the
+Pallas backends are refused when they are resolved
+(:func:`check_servable`, an :class:`~repro.errors.UnservableConfigError`
+at plan time) and ``"auto"`` is ``"jnp"`` everywhere.  The ``"jnp"``
+backend is also what the dry-run lowering uses on the 512-device mesh,
+where interpret-mode python loops would bloat the HLO.
 
 Shape contracts (match :mod:`repro.core.rns` / :mod:`repro.core.ntt`;
 violations raise immediately so a backend mismatch fails loudly):
@@ -68,13 +73,15 @@ from repro.core.params import (
     validate_backend,
 )
 from repro.analysis import walk as walk_mod
+from repro.errors import UnservableConfigError
 from repro.kernels import crt as crt_kernels
 from repro.kernels import ntt as ntt_kernels
 
 __all__ = [
     "BACKENDS",
     "SCHEDULES",
-    "auto_backend",
+    "PALLAS_BACKENDS",
+    "check_servable",
     "unbind",
     "resolve_backend",
     "resolve_schedule",
@@ -117,12 +124,26 @@ def _stage_backend(backend: str, cascade: bool = False) -> str:
     return backend
 
 
-def auto_backend() -> str:
-    """The concrete datapath ``backend="auto"`` resolves to (at plan
-    time, see :mod:`repro.api`): the fused single-kernel Pallas path on
-    TPU, the pure-jnp reference elsewhere — off-TPU the Pallas kernels
-    run in interpret mode, which is an emulation, not a fast path."""
-    return "pallas_fused_e2e" if _is_tpu() else "jnp"
+PALLAS_BACKENDS = ("pallas", "pallas_fused", "pallas_fused_e2e")
+
+
+def check_servable(backend: str) -> str:
+    """``backend`` if this process can compile it, else raise
+    :class:`~repro.errors.UnservableConfigError`.  On a TPU every Pallas
+    backend is refused: the kernels compute on int64 and Mosaic has no
+    64-bit layout (``ZeroDivisionError`` in its block-mapping check for
+    ``pallas``/``pallas_fused``, "only 32-bit scalars" or an unaligned
+    per-channel block for ``pallas_fused_e2e``).  :func:`repro.plan`
+    calls this, so the refusal comes at plan time and a compile failure
+    never reaches a dispatch."""
+    if backend in PALLAS_BACKENDS and _is_tpu():
+        raise UnservableConfigError(
+            f"backend={backend!r} does not compile for a TPU: its kernels "
+            f"compute on int64 and Mosaic has no 64-bit vector layout "
+            f"(use 'auto' or 'jnp')",
+            knob="backend", value=backend, alternatives=("auto", "jnp"),
+        )
+    return backend
 
 
 def resolve_backend(

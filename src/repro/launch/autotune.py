@@ -257,6 +257,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     sd.set_defaults(fn=_cmd_seed_default)
 
     args = ap.parse_args(argv)
+    from repro import compile_cache
+
+    compile_cache.enable()
     return int(args.fn(args))
 
 

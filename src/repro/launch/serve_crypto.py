@@ -4,11 +4,18 @@ through the batching :class:`repro.serve.crypto_engine.PolymulEngine`.
     PYTHONPATH=src python -m repro.launch.serve_crypto --requests 32 --slots 8
 
 The traffic generator interleaves heterogeneous presets (default: the
-paper's two operating points scaled to CPU-friendly n) and draws
-Poisson arrivals at ``--rate`` requests/s (0 = closed loop: everything
-arrives at t=0).  Requests are bucketed by plan config and served in
-padded micro-batches; the report shows throughput, latency percentiles
-and the bucket/trace accounting.
+paper's two operating points at its ring size n=4096, t=6 x v=30 and
+t=4 x v=45) and draws Poisson arrivals at ``--rate`` requests/s (0 =
+closed loop: everything arrives at t=0).  Requests are bucketed by plan
+config and served in padded micro-batches; the report shows throughput,
+latency percentiles and the bucket/trace accounting.  Each preset is
+compiled before traffic starts (``PolymulEngine.warmup``).  Compiling
+one n=4096 preset for a TPU takes about two minutes, so a quick try on
+the CPU passes small presets, e.g. ``--presets 64:3:30,64:4:45``.
+
+The exit code is non-zero when any request failed (a compile failure,
+or a dispatch that ran out of retries) or when a breaker opened, so a
+run that served its traffic on a fallback backend does not pass.
 
 Mesh mode: ``--mesh 2x2`` shards dispatches over a (data, model) host
 mesh — run under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
@@ -35,7 +42,7 @@ import numpy as np
 
 import jax
 
-from repro import api, obs
+from repro import api, compile_cache, obs
 from repro.serve.crypto_engine import PolymulEngine
 
 
@@ -115,7 +122,7 @@ def main(argv=None) -> int:
                     help="fixed batch slots per dispatch")
     ap.add_argument("--rate", type=float, default=0.0,
                     help="Poisson arrival rate in requests/s (0 = closed loop)")
-    ap.add_argument("--presets", default="64:3:30,64:4:45",
+    ap.add_argument("--presets", default="4096:6:30,4096:4:45",
                     help="comma-separated n:t:v presets, interleaved")
     ap.add_argument("--mesh", default="",
                     help="'DxM' (data x model) host mesh, e.g. 2x2")
@@ -139,6 +146,7 @@ def main(argv=None) -> int:
                          "stamped in) instead of text")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     mesh = build_mesh(args.mesh) if args.mesh else None
     span_log = obs.SpanLog(args.span_log) if args.span_log else None
     eng = PolymulEngine(batch_slots=args.slots, mesh=mesh,
@@ -148,13 +156,10 @@ def main(argv=None) -> int:
     plans = [eng.plan(**parse_preset(s)) for s in args.presets.split(",")]
     rng = np.random.default_rng(args.seed)
 
-    # warm: one padded dispatch per distinct config so the timed run
-    # measures serving, not compilation
+    # compile every config before traffic, so the timed run measures
+    # serving, and a config that cannot compile stops the run here
     for pl in plans:
-        shape = (pl.n, pl.config.seg_count)
-        eng.submit(pl, np.zeros(shape, np.int64), np.zeros(shape, np.int64))
-    eng.run_until_idle()
-    eng.reset_stats()
+        eng.warmup(pl)
 
     if args.async_dispatch:
         eng.start()
@@ -168,6 +173,7 @@ def main(argv=None) -> int:
 
     snap = eng.snapshot()
     ok = [f for f in futs if f.exception() is None]
+    rc = 1 if snap["failed"] or snap["breaker_opened"] else 0
     served = snap["served"]
     if span_log is not None:
         span_log.flush()
@@ -192,7 +198,7 @@ def main(argv=None) -> int:
             "snapshot": snap,
         }
         print(json.dumps(record, indent=1))
-        return 0
+        return rc
     print(f"served {served}/{len(futs)} requests in {wall:.3f}s "
           f"({served / wall:.1f} req/s, goodput {len(ok) / wall:.1f} "
           f"req/s) [seed={args.seed}]")
@@ -213,7 +219,7 @@ def main(argv=None) -> int:
         print(f"mesh axes={dict(mesh.shape)}")
     if args.span_log:
         print(f"span log: {args.span_log}")
-    return 0
+    return rc
 
 
 if __name__ == "__main__":

@@ -51,7 +51,7 @@ import time
 
 import numpy as np
 
-from repro import api, obs
+from repro import api, compile_cache, obs
 from repro.errors import EngineError
 from repro.serve.crypto_engine import PolymulEngine, PolymulFuture
 from repro.serve.faults import FaultInjector, FaultRule, spot_check
@@ -326,6 +326,7 @@ def main(argv=None) -> int:
                     help="write the metrics registry in Prometheus text "
                          "format after the run")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     record = run_soak(requests=args.requests, seed=args.seed,
                       batch_slots=args.slots, span_log_path=args.span_log)

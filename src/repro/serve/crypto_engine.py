@@ -46,6 +46,13 @@ both halves:
   :func:`repro.api.plan` with the same ``n/t/v``, so degraded results
   stay bit-exact; after ``breaker_cooldown_s`` the next dispatch probes
   the original backend and restores it on success.
+* **Compilation is not a dispatch.**  Each config's batch executable
+  is compiled ahead of time (:meth:`PolymulEngine.warmup`, or before a
+  bucket's first dispatch).  A lowering or compile error is
+  deterministic, so it never enters the retry/breaker path above: it
+  raises :class:`repro.errors.CompileFailedError` from ``warmup`` and
+  fails the bucket's requests with it, and the bucket serves nothing
+  else in its place.
 * **Async front end.**  ``start()`` launches a background dispatcher
   thread driving :meth:`PolymulEngine.step`; submission then overlaps
   host batching with device execution and futures support
@@ -54,14 +61,17 @@ both halves:
 * **Mesh mode.**  With ``mesh=``, dispatches run
   :func:`polymul_sharded`: the RNS channel axis of the residue cascade
   shard_maps over ``model`` and the batch axis over ``data``, with the
-  plan's table leaves sliced per shard (``partition.plan_leaf_specs``)
-  — the leaf-threaded ops layer (DESIGN §7) at work.
+  plan's table leaves placed one channel slice per ``model`` shard when
+  :meth:`PolymulEngine.plan` builds the plan
+  (``partition.plan_leaf_shardings``) — the leaf-threaded ops layer
+  (DESIGN §7) at work.
 
 Usage::
 
     eng = PolymulEngine(batch_slots=8, max_pending=64)
     with eng:                               # background dispatcher
         pl = eng.plan(n=4096, t=6, v=30)
+        eng.warmup(pl)                      # compile now, not on traffic
         fut = eng.submit(pl, za, zb, deadline=0.5)   # za, zb: (n, S)
         limbs = fut.result(timeout=5.0)     # (n, L), or raises EngineError
 
@@ -74,6 +84,7 @@ Fault injection for soak testing wraps ``engine.executor``
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import itertools
 import math
@@ -84,11 +95,12 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from repro import api
-from repro.compat import shard_map
 from repro.errors import (
     BackendFailedError,
+    CompileFailedError,
     DeadlineExceededError,
     QueueFullError,
 )
@@ -168,6 +180,13 @@ def negacyclic_mul_sharded(pl: api.Plan, a, b, *, mesh):
             f"({bsize}-way); pad the batch (the engine's slot padding "
             f"guarantees this)"
         )
+    return _cascade_sharded(pl, a, b, mesh=mesh)
+
+
+# Jitted so an eager caller gets one compiled program: shard_map outside
+# jit runs op by op, minutes per call for one small wide-width product.
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def _cascade_sharded(pl: api.Plan, a, b, *, mesh):
     leaf_specs = partition.plan_leaf_specs(mesh, pl)
     res_spec = partition.polymul_specs(mesh, pl)["residues"]
 
@@ -201,6 +220,11 @@ def polymul_sharded(pl: api.Plan, za, zb, *, mesh):
             f"polymul_sharded serves int64/wide-width plans only "
             f"(got width={cfg.width!r})"
         )
+    return _polymul_sharded(pl, za, zb, mesh=mesh)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def _polymul_sharded(pl: api.Plan, za, zb, *, mesh):
     pol = ctx_mod.make_crypto_policy(mesh, pl)
     za = pol(za, "segments")
     zb = pol(zb, "segments")
@@ -564,6 +588,10 @@ class PolymulEngine:
         self._exec = jax.jit(
             _run, donate_argnums=(1, 2) if donate else ()
         )
+        # PlanConfig -> its compiled batch executable, or the
+        # CompileFailedError it raised (so a bucket that cannot compile
+        # fails fast instead of recompiling per dispatch).
+        self._compiled: dict = {}
         # The raw batch executor; fault injectors and tests wrap THIS
         # attribute (repro.serve.faults.FaultInjector.install).  Every
         # dispatch goes through it, so a wrapper sees one call per
@@ -574,9 +602,57 @@ class PolymulEngine:
     def plan(self, n: int = 4096, t: int = 6, v: int = 30, **kw) -> api.Plan:
         """Build-or-fetch a plan, cached by its resolved
         :func:`api.plan_key` — repeated preset lookups share one Plan
-        object (and, transitively, one set of device tables)."""
+        object (and, transitively, one set of device tables).  In mesh
+        mode the plan's table leaves are placed on the mesh, each RNS
+        channel's tables on the ``model`` shard that serves it."""
         pl = api.plan(n=n, t=t, v=v, **kw)
-        return self._plans.setdefault(api.plan_key(pl), pl)
+        cfg = api.plan_key(pl)
+        if (self.mesh is not None and cfg not in self._plans
+                and cfg.width in ("int64", "wide")):
+            pl = api.Plan(
+                config=pl.config, params=pl.params,
+                consts=jax.device_put(
+                    pl.consts, partition.plan_leaf_shardings(self.mesh, pl)
+                ),
+            )
+        return self._plans.setdefault(cfg, pl)
+
+    def warmup(self, pl: api.Plan) -> float:
+        """Compile the batch executable for ``pl``'s config before any
+        traffic; returns the seconds spent (0.0 when it was already
+        compiled, and for the host-only oracle width).  Raises
+        :class:`repro.errors.CompileFailedError` when the plan does not
+        lower or compile for this device."""
+        cfg = api.plan_key(pl)
+        if cfg.width == "oracle":
+            return 0.0
+        t0 = time.perf_counter()
+        self._executable(self._plans.setdefault(cfg, pl))
+        return time.perf_counter() - t0
+
+    def _executable(self, pl: api.Plan):
+        """``pl``'s compiled batch executable, compiled on first use for
+        the padded ``(batch_slots, n, S)`` operand shape."""
+        cfg = api.plan_key(pl)
+        got = self._compiled.get(cfg)
+        if got is None:
+            z = jax.ShapeDtypeStruct(
+                (self.batch_slots, cfg.n, cfg.seg_count), jnp.int64
+            )
+            try:
+                got = self._exec.lower(pl, z, z).compile()
+            except Exception as e:  # noqa: BLE001 — any lowering/compile error
+                got = CompileFailedError(
+                    f"bucket {_bucket_key_str(cfg)}: the batch executable "
+                    f"did not compile for {jax.devices()[0].platform}: "
+                    f"{type(e).__name__}: {e}",
+                    backend=cfg.backend,
+                )
+                got.__cause__ = e
+            self._compiled[cfg] = got
+        if isinstance(got, CompileFailedError):
+            raise got
+        return got
 
     # -- request intake ------------------------------------------------
     def _validate_submit(self, pl, za, zb):
@@ -738,7 +814,7 @@ class PolymulEngine:
         if api.plan_key(pl).width == "oracle":
             # Host-only width: no tracing — runs the exact bigint path.
             return np.asarray(api.polymul(pl, za, zb))
-        return np.asarray(self._exec(pl, jnp.asarray(za), jnp.asarray(zb)))
+        return np.asarray(self._executable(pl)(pl, za, zb))
 
     def _fallback_plan(self, pl: api.Plan) -> api.Plan | None:
         """The next plan down the degradation chain for ``pl`` — same
@@ -878,11 +954,17 @@ class PolymulEngine:
             resolved += self._resolve_shed(shed)
             if not reqs:
                 continue  # everything admitted this round was shed
+            cfg = api.plan_key(use_plan)
+            if cfg.width != "oracle":
+                try:
+                    self._executable(use_plan)
+                except CompileFailedError as e:
+                    return resolved + self._on_compile_failure(
+                        use_plan, reqs, e
+                    )
             with self._cond:
                 dispatch_idx = self._dispatch_seq
                 self._dispatch_seq += 1
-            cfg = api.plan_key(use_plan)
-            traces_before = len(self._trace_log)
             t0 = time.perf_counter()
             for r in reqs:
                 if r.attempts == 0:  # first attempt: the queue wait
@@ -913,15 +995,10 @@ class PolymulEngine:
                     bucket, use_plan, probing, reqs, e
                 )
                 return resolved
-            # A dispatch that triggered a jit trace spent its wall time
-            # compiling; folding that into the EWMA would make the
-            # deadline admission shed everything behind it.
-            exec_s = (
-                time.perf_counter() - t0
-                if len(self._trace_log) == traces_before else None
-            )
+            # Compilation happened before t0, so this is service time.
             resolved += self._on_dispatch_success(
-                bucket, probing, reqs, out, pad, dispatch_idx, exec_s
+                bucket, probing, reqs, out, pad, dispatch_idx,
+                time.perf_counter() - t0,
             )
             return resolved
 
@@ -948,11 +1025,10 @@ class PolymulEngine:
                         "breaker_recovered", engine=self.name,
                         bucket=_bucket_key_str(bucket.key),
                     )
-            if exec_s is not None:  # None: compile dispatch, not service
-                bucket.ewma_service_s = (
-                    exec_s if bucket.ewma_service_s == 0.0
-                    else 0.75 * bucket.ewma_service_s + 0.25 * exec_s
-                )
+            bucket.ewma_service_s = (
+                exec_s if bucket.ewma_service_s == 0.0
+                else 0.75 * bucket.ewma_service_s + 0.25 * exec_s
+            )
             self._m["dispatches"].inc()
             self._m["served"].inc(len(reqs))
             self._m["padded_slots"].inc(pad)
@@ -1015,6 +1091,30 @@ class PolymulEngine:
             err.__cause__ = exc
             r.future._fail(err, latency_s=now - r.t_submit)
         return len(failed)
+
+    def _on_compile_failure(self, use_plan, reqs, exc) -> int:
+        """Fail exactly the popped requests with the typed compile error:
+        no retry, no backoff and no breaker, since recompiling the same
+        program fails the same way."""
+        now = time.perf_counter()
+        with self._cond:
+            self._inflight -= len(reqs)
+            self._m["failed"].inc(len(reqs))
+            self._cond.notify_all()
+        backend = api.plan_key(use_plan).backend
+        for r in reqs:
+            if r.span is not None:
+                r.span.finish(
+                    "failed", backend=backend, attempts=r.attempts,
+                    error=type(exc).__name__, latency_s=now - r.t_submit,
+                )
+            err = CompileFailedError(
+                f"request seq {r.seq}: {exc}", request_seq=r.seq,
+                backend=backend, attempts=r.attempts,
+            )
+            err.__cause__ = exc.__cause__
+            r.future._fail(err, latency_s=now - r.t_submit)
+        return len(reqs)
 
     def _degrade_locked(self, bucket: _Bucket, now: float) -> bool:
         """Open the bucket's breaker: activate (building if needed) the

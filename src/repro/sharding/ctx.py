@@ -59,7 +59,7 @@ def moe_scatter(slot, xk, n_rows: int):
         return scatter_rows(slot, xk)
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     ba = pol.batch_axes
     size = pol.batch_size
@@ -89,7 +89,7 @@ def moe_gather(eout, slot):
         return gather_rows(eout, slot)
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     ba = pol.batch_axes
     if slot.shape[0] % pol.batch_size != 0:

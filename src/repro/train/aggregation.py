@@ -50,7 +50,7 @@ def compressed_psum(grads, key, mesh, axis: str = "pod"):
     over <= 2^23 pods cannot overflow int32)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     leaves, treedef = jax.tree.flatten(grads)
     keys = list(jax.random.split(key, len(leaves)))

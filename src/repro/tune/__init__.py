@@ -9,8 +9,8 @@ package chooses them from measurement instead of hand-picked defaults:
   workload key ``(n, t, v, batch)`` (pruning unservable combos via the
   plan-error taxonomy) and measure each with warm-up-excluded compiled
   wall-clock (AOT ``jax.jit(...).lower(...).compile()`` — a real XLA:CPU
-  compile today, Mosaic/TPU transparently when present), falling back to
-  eager interpret timing when a candidate cannot compile;
+  compile today, Mosaic/TPU transparently when present), recording a
+  candidate that cannot compile as failed rather than timing it;
 * :mod:`repro.tune.table` — the persistent versioned JSON tuning table,
   keyed by device kind + workload key, consulted by
   ``repro.plan(..., tuning=...)`` at plan time (resolution order:

@@ -15,8 +15,10 @@ the four tunable plan knobs (``backend``, ``schedule``, ``row_blk``,
    today (interpret-mode Pallas inlines kernel bodies into the traced
    program, so XLA compiles the full datapath), Mosaic/TPU or Triton/GPU
    transparently when that is the default backend — and keeps the
-   optimized HLO for the cost model.  A candidate that fails to compile
-   falls back to eager interpret timing (``mode="eager"``, no HLO).
+   optimized HLO for the cost model.  A candidate that fails to lower or
+   compile is recorded as ``status="failed"`` with the compiler's error
+   and is not timed: timing some other path in its place would credit
+   the candidate with a speed it does not have.
 
 The winner is the fastest measured config, with a stability bias: the
 static default keeps the crown unless a challenger beats it by more
@@ -155,20 +157,18 @@ def measure_plan(
     Returns ``us_per_poly`` (median over ``iters`` timed calls, divided
     by the batch), ``compile_s``, ``mode`` ("compiled" | "eager") and
     the optimized ``hlo`` text (compiled mode only).  Oracle-width plans
-    and compile failures time the eager path.
+    run on the host and time the eager path; for every other plan a
+    lowering or compile error propagates to the caller.
     """
     batch = int(np.shape(za)[0]) if np.ndim(za) >= 3 else 1
     compiled = None
     hlo = None
     compile_s = None
     if api.plan_key(pl).width != "oracle":
-        try:
-            t0 = time.perf_counter()
-            compiled = jax.jit(fn).lower(pl, za, zb).compile()
-            compile_s = time.perf_counter() - t0
-            hlo = compiled.as_text()
-        except Exception:  # interpret-mode fallback below  # noqa: BLE001
-            compiled = None
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(pl, za, zb).compile()
+        compile_s = time.perf_counter() - t0
+        hlo = compiled.as_text()
     run: Callable[[], Any] = (
         (lambda: compiled(pl, za, zb)) if compiled is not None
         else (lambda: fn(pl, za, zb))
@@ -267,8 +267,12 @@ def sweep_workload(
     by_name = {r["name"]: r for r in records}
     for cand, pl in plans:
         say(f"  measuring {cand.name} ...")
-        m = measure_plan(pl, za, zb, iters=iters, warmup=warmup)
         rec = by_name[cand.name]
+        try:
+            m = measure_plan(pl, za, zb, iters=iters, warmup=warmup)
+        except Exception as e:  # noqa: BLE001 — a candidate that does not compile
+            rec.update(status="failed", error=type(e).__name__, reason=str(e))
+            continue
         rec.update(
             us_per_poly=m["us_per_poly"],
             compile_s=m["compile_s"],
@@ -299,6 +303,12 @@ def sweep_workload(
         ]
     )
 
+    if not measured or measured[0]["name"] != plans[0][0].name:
+        raise PlanError(
+            f"sweep {wl.key}: the default candidate failed to compile: "
+            f"{by_name[plans[0][0].name].get('reason')}",
+            knob="workload", value=wl.key, alternatives=(),
+        )
     default_rec = measured[0]  # DEFAULT_CANDIDATE is always first
     winner_rec = min(measured, key=lambda r: r["us_per_poly"])
     if winner_rec["us_per_poly"] >= default_rec["us_per_poly"] * (1 - WINNER_MARGIN):

@@ -50,7 +50,7 @@ class TestHloAnalyzer:
     def test_collective_bytes_psum(self):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("data",))
 

@@ -67,7 +67,8 @@ class TestNttKernels:
         a = _rand_res(p, 8, row_blk)
         ct = p.tables
         got = ntt_kernels.ntt_channels_pallas(
-            a, jnp.asarray(ct.qs), jnp.asarray(ct.fwd), row_blk=row_blk
+            a, jnp.asarray(ct.qs), jnp.asarray(ct.fwd), row_blk=row_blk,
+            interpret=True,
         )
         want = ops.ntt_forward(a, p, use_pallas=False)
         assert np.array_equal(np.asarray(got), np.asarray(want))
@@ -84,6 +85,7 @@ class TestNttKernels:
             jnp.asarray(a),
             jnp.asarray([q], dtype=jnp.int32),
             jnp.asarray(tb.fwd[None, :].astype(np.int32)),
+            interpret=True,
         )
         want = ntt_core.ntt_raw(jnp.asarray(a[0]).astype(jnp.int64), jnp.asarray(tb.fwd), q)
         assert np.array_equal(np.asarray(got)[0], np.asarray(want).astype(np.int32))

@@ -279,6 +279,20 @@ class TestMeshShardedCascade:
         assert eng.stats["dispatches"] == 2
         assert eng.stats["padded_slots"] == 2
 
+    def test_engine_mesh_mode_places_tables_per_shard(self, host_mesh_4):
+        """The engine's mesh-mode plan holds each model shard's channel
+        tables on that shard's devices, not everything on device 0."""
+        eng = PolymulEngine(batch_slots=4, mesh=host_mesh_4)
+        pl = eng.plan(n=64, t=6, v=30)
+        specs = partition.plan_leaf_specs(host_mesh_4, pl)
+        sharded = [k for k, sp in specs.items() if len(sp) and sp[0] == "model"]
+        assert sharded
+        for k in sharded:
+            shards = pl.consts[k].addressable_shards
+            assert {s.data.shape[0] for s in shards} == {3}
+            assert len({s.device for s in shards}) == 4
+        assert eng.plan(n=64, t=6, v=30) is pl
+
     def test_engine_mesh_mode_rejects_nonsharding_slots(self, host_mesh_4):
         with pytest.raises(ValueError, match="batch_slots"):
             PolymulEngine(batch_slots=3, mesh=host_mesh_4)
@@ -416,6 +430,37 @@ class TestFailureSemantics:
         ]
         assert fut2.exception() is None
 
+    def test_compile_failure_is_typed_and_never_retried(self):
+        """A plan whose executable does not compile fails at warmup with
+        CompileFailedError, and its requests fail with it at once: no
+        retry, no backoff, no breaker, no other backend in its place."""
+        from repro.errors import BackendFailedError, CompileFailedError
+
+        class Refuses:
+            def lower(self, *args):
+                raise ValueError("the compiler refused the kernel")
+
+        rng = np.random.default_rng(5)
+        eng = PolymulEngine(batch_slots=2, breaker_threshold=1)
+        pl = eng.plan(n=64, t=3, v=30, backend="pallas_fused_e2e")
+        eng._exec = Refuses()
+        with pytest.raises(CompileFailedError) as ei:
+            eng.warmup(pl)
+        assert isinstance(ei.value.__cause__, ValueError)
+        futs = [eng.submit(pl, *self._mk(pl, rng)) for _ in range(3)]
+        eng.run_until_idle()
+        for f in futs:
+            exc = f.exception()
+            assert isinstance(exc, CompileFailedError)
+            assert isinstance(exc, BackendFailedError)
+            assert isinstance(exc.__cause__, ValueError)
+            assert exc.attempts == 0
+        snap = eng.snapshot()
+        assert snap["failed"] == 3
+        assert snap["retried"] == snap["dispatch_failures"] == 0
+        assert snap["breaker_opened"] == snap["degraded_buckets"] == 0
+        assert eng.trace_count == 0
+
     def test_deadline_shed_typed_never_dropped(self):
         from repro.errors import DeadlineExceededError
 
@@ -517,3 +562,42 @@ class TestFailureSemantics:
             assert np.array_equal(out, want)
         assert eng.stats["served"] == 10
         assert eng.pending() == 0
+
+
+class TestEntryPoints:
+    def test_serve_crypto_exit_code_reports_failures(
+        self, monkeypatch, tmp_path
+    ):
+        """The launcher exits 0 on a clean run and non-zero once any
+        request failed, so a run that did not serve cannot pass."""
+        from repro.launch import serve_crypto
+
+        # a set variable makes compile_cache.enable() leave JAX's config
+        # alone, so this test does not turn the cache on for the worker
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        argv = ["--presets", "16:2:30", "--requests", "4", "--slots", "2"]
+        assert serve_crypto.main(argv) == 0
+
+        def dead(self, pl, za, zb):
+            raise RuntimeError("device fault")
+
+        monkeypatch.setattr(PolymulEngine, "_execute_batch", dead)
+        assert serve_crypto.main(argv) == 1
+
+    def test_compile_cache_placed_from_outside(self, monkeypatch, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without
+        it the cache goes to one fixed directory in the checkout."""
+        from repro import compile_cache
+
+        was = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        try:
+            got = compile_cache.enable()
+            assert got == str(compile_cache.DEFAULT_DIR)
+            assert compile_cache.DEFAULT_DIR.parent.joinpath("chip_smoke.py").exists()
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)

@@ -245,6 +245,42 @@ class TestSweep:
         tab = TuningTable()
         tab.put(**rep["entry"])
 
+    def test_candidate_that_does_not_compile_is_failed_not_timed(
+        self, monkeypatch
+    ):
+        """A compile failure is recorded as the candidate's result; no
+        other path is timed in its place.  A default that does not
+        compile fails the sweep."""
+        from repro.errors import PlanError
+        from repro.tune import sweep as sweep_mod
+
+        real = sweep_mod.measure_plan
+
+        def compiles_jnp_only(pl, za, zb, **kw):
+            if repro.plan_key(pl).backend != "jnp":
+                raise ValueError("the compiler refused the kernel")
+            return real(pl, za, zb, **kw)
+
+        monkeypatch.setattr(sweep_mod, "measure_plan", compiles_jnp_only)
+        wl = sweep_mod.Workload(n=64, t=3, v=30, batch=2)
+        cands = (
+            sweep_mod.DEFAULT_CANDIDATE,
+            sweep_mod.Candidate(backend="pallas", schedule="radix2"),
+        )
+        rep = sweep_mod.sweep_workload(wl, cands, iters=1, warmup=0)
+        failed = [c for c in rep["candidates"] if c["status"] == "failed"]
+        assert [c["config"]["backend"] for c in failed] == ["pallas"]
+        assert failed[0]["error"] == "ValueError"
+        assert "us_per_poly" not in failed[0]
+        assert rep["entry"]["winner"]["backend"] == "jnp"
+
+        def refuses_all(pl, za, zb, **kw):
+            raise ValueError("the compiler refused the kernel")
+
+        monkeypatch.setattr(sweep_mod, "measure_plan", refuses_all)
+        with pytest.raises(PlanError, match="default candidate failed"):
+            sweep_mod.sweep_workload(wl, cands, iters=1, warmup=0)
+
     def test_measured_winner_resolves_through_plan(self, tmp_path):
         from repro.tune import sweep as sweep_mod
 
