@@ -721,7 +721,7 @@ def polymul(pl: Plan, za: Any, zb: Any) -> jax.Array:
     if cfg.width == "int64":
         # The profiler stage scopes nest under this root:
         # parentt.polymul/parentt.{decompose,cascade,compose,fused_e2e}
-        # (obs stage profiling, DESIGN.md §12).
+        # (stage scopes, DESIGN.md §12).
         with jax.named_scope("parentt.polymul"):
             return ops_mod.fused_polymul_e2e(
                 za, zb, _bound_params(pl), backend=cfg.backend,

@@ -1,20 +1,17 @@
 """Observability report CLI: render latency / throughput / lifecycle
-breakdowns from a span log, audit span conservation, validate exporter
-output, and (optionally) run the per-stage profiling harness.
+breakdowns from a span log, audit span conservation, and validate
+exporter output.
 
     PYTHONPATH=src python -m repro.launch.obs_report spans.jsonl
     PYTHONPATH=src python -m repro.launch.obs_report spans.jsonl --check
     PYTHONPATH=src python -m repro.launch.obs_report --prom metrics.prom
-    PYTHONPATH=src python -m repro.launch.obs_report --stages 64:3:30
 
 ``--check`` is the span-conservation gate of the ``obs-smoke`` CI job:
 every admitted request span must carry exactly one terminal status
 (``resolved`` / ``shed`` / ``failed``); any violation exits non-zero
 with the offending trace IDs.  ``--prom`` parses a Prometheus text-format
 file through the strict validator (:func:`repro.obs.parse_prometheus`)
-and exits non-zero on malformed expositions.  ``--stages n:t:v`` runs
-the compiled stage-timing harness and prints measured stage shares
-beside the ``hbm_traffic_model`` predictions with per-stage drift.
+and exits non-zero on malformed expositions.
 """
 from __future__ import annotations
 
@@ -130,33 +127,6 @@ def _print_report(rep: dict[str, Any]) -> None:
         print(f"[VIOLATION] {v}", file=sys.stderr)
 
 
-def _run_stages(spec: str, *, batch: int, iters: int,
-                as_json: bool) -> int:
-    import repro
-
-    n, t, v = (int(x) for x in spec.split(":"))
-    pl = repro.plan(n=n, t=t, v=v)
-    rec = obs.stage_timings(pl, batch=batch, iters=iters)
-    if as_json:
-        print(json.dumps(rec, indent=1))
-        return 0
-    print(f"stage timings n={n} t={t} v={v} backend={rec['backend']} "
-          f"batch={rec['batch']}")
-    print(f"{'stage':<12}{'measured':>12}{'share':>8}{'model':>8}"
-          f"{'drift':>8}")
-    for stage in obs.STAGES:
-        s = rec["stages"][stage]
-        print(f"{stage:<12}{s['seconds'] * 1e6:>10.1f}us"
-              f"{s['share_measured']:>8.1%}{s['share_predicted']:>8.1%}"
-              f"{s['drift']:>8.1%}")
-    print(f"sum-of-stages {rec['stage_sum_s'] * 1e6:.1f}us, "
-          f"e2e {rec['e2e_s'] * 1e6:.1f}us "
-          f"(fusion speedup {rec['fusion_speedup']:.2f}x)")
-    tc = rec["transform_cost_model"]
-    print(f"transform_cost_model: {tc}")
-    return 0
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("span_log", nargs="?", default=None,
@@ -166,19 +136,12 @@ def main(argv=None) -> int:
                          "(the obs-smoke CI gate)")
     ap.add_argument("--prom", default=None, metavar="FILE",
                     help="validate a Prometheus text-format exposition")
-    ap.add_argument("--stages", default=None, metavar="N:T:V",
-                    help="run the compiled per-stage profiling harness "
-                         "for one plan preset")
-    ap.add_argument("--batch", type=int, default=4,
-                    help="profiling batch rows (--stages)")
-    ap.add_argument("--iters", type=int, default=10,
-                    help="profiling timing iterations (--stages)")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of text")
     args = ap.parse_args(argv)
 
-    if args.span_log is None and args.prom is None and args.stages is None:
-        ap.error("nothing to do: pass a span log, --prom, or --stages")
+    if args.span_log is None and args.prom is None:
+        ap.error("nothing to do: pass a span log or --prom")
 
     rc = 0
     if args.prom is not None:
@@ -203,10 +166,6 @@ def main(argv=None) -> int:
             _print_report(rep)
         if args.check and rep["violations"]:
             rc = 1
-
-    if args.stages is not None:
-        rc = max(rc, _run_stages(args.stages, batch=args.batch,
-                                 iters=args.iters, as_json=args.json))
     return rc
 
 

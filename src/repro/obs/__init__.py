@@ -1,5 +1,5 @@
-"""Unified observability layer: metrics registry, request-scoped
-tracing, per-stage device profiling, and exporters (DESIGN.md §12).
+"""Unified observability layer: metrics registry, request- and
+dispatch-scoped tracing, and exporters (DESIGN.md §12).
 
 Quickstart::
 
@@ -14,8 +14,8 @@ Quickstart::
     obs.conservation(obs.read_jsonl("spans.jsonl"))  # lifecycle audit
 
 ``python -m repro.launch.obs_report spans.jsonl`` renders the
-latency/throughput/stage-breakdown report and runs the conservation
-gate from the CLI.
+latency/throughput/lifecycle report and runs the conservation gate
+from the CLI.
 """
 from repro.obs.export import parse_prometheus, to_json, to_prometheus
 from repro.obs.metrics import (
@@ -28,7 +28,6 @@ from repro.obs.metrics import (
     registry,
     reset_default_registry,
 )
-from repro.obs.profiling import STAGES, predicted_stage_bytes, stage_timings
 from repro.obs.tracing import (
     TERMINAL_STATUSES,
     Span,
@@ -39,7 +38,6 @@ from repro.obs.tracing import (
 
 __all__ = [
     "HIST_GROWTH",
-    "STAGES",
     "TERMINAL_STATUSES",
     "Counter",
     "Gauge",
@@ -50,11 +48,9 @@ __all__ = [
     "conservation",
     "default_buckets",
     "parse_prometheus",
-    "predicted_stage_bytes",
     "read_jsonl",
     "registry",
     "reset_default_registry",
-    "stage_timings",
     "to_json",
     "to_prometheus",
 ]

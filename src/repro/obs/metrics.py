@@ -1,8 +1,8 @@
 """Process-wide metrics registry: counters, gauges, histograms — with
 labels, lock-protected, zero-dependency.
 
-The serving engine, the fault injector, the autotuner sweep, and the
-profiling harness all count things; before this module each kept its own
+The serving engine, the fault injector and the autotuner sweep all
+count things; before this module each kept its own
 ad-hoc dict (``PolymulEngine.stats``, ``FaultInjector.log`` tallies,
 sweep report fields).  The registry unifies them behind one vocabulary
 so every exporter (:mod:`repro.obs.export`), the engine's versioned
@@ -18,16 +18,20 @@ Histogram resolution bound
 --------------------------
 Histograms use exponential bucket bounds with growth factor
 :data:`HIST_GROWTH` (default sqrt(2)).  :meth:`Histogram.quantile`
-interpolates linearly inside the bucket holding the requested rank, so
-the estimate and the exact sample quantile always land in the same
-bucket or adjacent ranks of it; the documented accuracy contract is::
+interpolates linearly inside the bucket holding the sample of rank
+``floor(q * (count - 1))``.  The estimate is bracketed by the
+nearest-rank order statistics around that rank, ``lower`` and
+``higher`` (``numpy.percentile(values, 100 * q, method="lower")`` and
+``method="higher"``); the documented accuracy contract is::
 
-    exact / GROWTH - lowest_bound <= quantile(q) <= exact * GROWTH + lowest_bound
+    lower / GROWTH - lowest_bound <= quantile(q) <= higher * GROWTH + lowest_bound
 
 i.e. relative error bounded by the bucket growth factor, plus an
 absolute floor of the first bucket bound for values below resolution.
-``tests/test_obs.py`` property-tests this bound against exact
-``numpy.percentile`` on latency- and queue-wait-shaped series.
+Numpy's default, *interpolated* percentile is not bracketed this way:
+between two far-apart samples it falls where no bucket holds.
+``tests/test_obs.py`` property-tests this bound on latency- and
+queue-wait-shaped series.
 
 Thread safety: one registry lock serializes metric creation and child
 lookup; each child carries its own lock for observations, so hot-path

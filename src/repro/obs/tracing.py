@@ -17,19 +17,33 @@ Span state machine (DESIGN.md §12)::
                     \\-> shed                  # deadline passed / unmeetable
                     \\-> ... retry/breaker_open events ... -> failed
 
+Each engine dispatch attempt is a span too: ``engine.step``, with one
+child span per phase (``engine.pack``, ``engine.upload``,
+``engine.launch``, ``engine.device_wait``, ``engine.readback``,
+``engine.resolve``), each carrying ``parent_id`` = the step's id.
+:meth:`SpanLog.traced` and :meth:`Span.child` record such spans and, over
+the same ``with``, a ``jax.profiler.TraceAnnotation`` of the same name,
+so a profiler trace shows the same phases on its host plane.
+
 Engine-level happenings that are not tied to one request (circuit
 breaker opening/closing, probe dispatches) are logged as *event*
 records, so a log line is one of two kinds::
 
-    {"kind": "span",  "trace_id": "...", "name": "request", "status": ...,
-     "t_start": ..., "t_end": ..., "attrs": {...}, "events": [...]}
+    {"kind": "span",  "trace_id": "...", "parent_id": null,
+     "name": "request", "status": ..., "t_start": ..., "t_end": ...,
+     "attrs": {...}, "events": [...]}
     {"kind": "event", "name": "breaker_open", "t": ..., "attrs": {...}}
 
 Timestamps are ``time.perf_counter()`` seconds (monotonic, same clock
 as the engine's deadlines) plus one ``t_unix`` wall anchor on each
 record — derived from a single per-log wall/monotonic anchor pair, not
 a syscall per span — so logs from one process are internally orderable
-and roughly placeable in wall time.
+and placeable in wall time.  The profiler stamps its host events with
+the same wall clock (``CLOCK_REALTIME``, ns since the Unix epoch): an
+``.xplane.pb`` event lies at the ``Task Environment`` plane's
+``profile_start_time`` plus its ``start_ns``, and
+:meth:`SpanLog.to_trace_ns` maps a span's ``perf_counter`` time there,
+so a span log of a whole run lays over a trace of any stretch of it.
 
 Overhead: recording is append-to-list under one lock, no I/O; the JSONL
 serialization happens only at :meth:`SpanLog.flush`.  Trace IDs come
@@ -65,27 +79,30 @@ TERMINAL_STATUSES = ("resolved", "shed", "failed")
 _trace_counter = itertools.count()
 
 
-def _mint_trace_id(prefix: str) -> str:
-    return f"{prefix}-{os.getpid():x}-{next(_trace_counter):08x}"
-
-
 class Span:
-    """One in-flight request trace.  Engine-internal mutation only; the
-    record becomes immutable once :meth:`finish` hands it to the log."""
+    """One in-flight trace: a request, or a dispatch and its phases.
+    Engine-internal mutation only; the record becomes immutable once
+    :meth:`finish` hands it to the log."""
 
-    __slots__ = ("trace_id", "name", "t_start", "attrs",
-                 "events", "status", "t_end", "_log")
+    __slots__ = ("trace_id", "parent_id", "name", "t_start", "attrs",
+                 "events", "status", "t_end", "failed_child", "_log",
+                 "_annotation", "_meta")
 
     def __init__(self, log: "SpanLog", name: str, trace_id: str,
-                 attrs: dict[str, Any]) -> None:
+                 attrs: dict[str, Any], parent_id: str | None = None) -> None:
         self._log = log
         self.trace_id = trace_id
+        self.parent_id = parent_id
         self.name = name
         self.t_start = time.perf_counter()
         self.attrs = attrs
         self.events: list[dict[str, Any]] = []
         self.status: str | None = None
         self.t_end: float | None = None
+        # the child span that an exception left, if any (traced spans)
+        self.failed_child: str | None = None
+        self._annotation = None  # the profiler annotation (traced spans)
+        self._meta: dict[str, Any] = {}  # its metadata, children inherit it
 
     def event(self, name: str, **attrs: Any) -> None:
         """Append a timestamped event; no-op after the span finished
@@ -112,10 +129,29 @@ class Span:
             self.attrs.update(attrs)
         self._log._emit(self.to_record())
 
+    def discard(self) -> None:
+        """Close the span without recording it (a traced stretch that
+        turned out to hold nothing worth a span)."""
+        self.status = "discarded"
+
+    def annotate(self, **meta: Any) -> None:
+        """Add attrs that also go on the span's profiler annotation and
+        on those of the children opened after this call."""
+        self.attrs.update(meta)
+        self._meta.update(meta)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**meta)
+
+    def child(self, name: str, **attrs: Any):
+        """A traced child span of this one (see :meth:`SpanLog.traced`);
+        it inherits the metadata set by :meth:`annotate`."""
+        return _Traced(self._log, name, self, attrs)
+
     def to_record(self) -> dict[str, Any]:
         return {
             "kind": "span",
             "trace_id": self.trace_id,
+            "parent_id": self.parent_id,
             "name": self.name,
             "status": self.status,
             "t_start": self.t_start,
@@ -143,9 +179,12 @@ class SpanLog:
         # precomputed so minting a trace ID is one format, zero syscalls
         self._id_prefix = f"{trace_prefix}-{os.getpid():x}-"
         # one wall/monotonic anchor pair; every record's t_unix derives
-        # from it instead of a per-record time.time() call
-        self._anchor_perf = time.perf_counter()
-        self._anchor_unix = time.time()
+        # from it instead of a per-record time.time() call.  The wall
+        # reading is bracketed by two perf_counter readings and paired
+        # with their midpoint.
+        t0 = time.perf_counter()
+        self._anchor_ns = time.time_ns()
+        self._anchor_perf = (t0 + time.perf_counter()) / 2
         self._lock = threading.Lock()
         self._records: list[dict[str, Any]] = []
         self._unflushed: list[dict[str, Any]] = []
@@ -153,12 +192,33 @@ class SpanLog:
     def to_unix(self, t_perf: float) -> float:
         """Map a ``perf_counter`` timestamp to wall time via the log's
         anchor pair."""
-        return self._anchor_unix + (t_perf - self._anchor_perf)
+        return self._anchor_ns / 1e9 + (t_perf - self._anchor_perf)
+
+    def to_trace_ns(self, t_perf: float) -> int:
+        """Map a ``perf_counter`` timestamp to the profiler's host clock:
+        integer ns since the Unix epoch, where an ``.xplane.pb`` event
+        lies at ``profile_start_time + start_ns`` (module docstring).
+        Both clocks tick in step, so the mapping is the anchor's one
+        offset; PERF.md gives its residual against a chip trace."""
+        return self._anchor_ns + round((t_perf - self._anchor_perf) * 1e9)
 
     # -- recording -----------------------------------------------------
-    def start_span(self, name: str, **attrs: Any) -> Span:
+    def start_span(self, name: str, *, parent: Span | None = None,
+                   **attrs: Any) -> Span:
         tid = f"{self._id_prefix}{next(_trace_counter):08x}"
-        return Span(self, name, tid, attrs)
+        return Span(self, name, tid, attrs,
+                    None if parent is None else parent.trace_id)
+
+    def traced(self, name: str, **attrs: Any):
+        """Context manager: a span of this log named ``name`` and, over
+        the same ``with``, a ``jax.profiler.TraceAnnotation`` of the same
+        name (host plane of a profiler trace, when one is recording).
+        Yields the :class:`Span`.  On leaving, a span still open closes
+        ``ok``; an exception closes it ``failed`` with the error type and
+        the ``phase`` that raised (its failed child's name, else its
+        own) and marks it as its parent's ``failed_child``.  Children
+        come from :meth:`Span.child`."""
+        return _Traced(self, name, None, attrs)
 
     def event(self, name: str, **attrs: Any) -> None:
         """Record an engine-level (non-request) event."""
@@ -212,6 +272,45 @@ class SpanLog:
 
     def __exit__(self, *exc: Any) -> None:
         self.flush()
+
+
+class _Traced:
+    """The context manager behind :meth:`SpanLog.traced` and
+    :meth:`Span.child` (a class, not a generator: it runs on every
+    traced dispatch phase)."""
+
+    __slots__ = ("_log", "_name", "_parent", "_attrs", "_span")
+
+    def __init__(self, log: SpanLog, name: str, parent: Span | None,
+                 attrs: dict[str, Any]) -> None:
+        self._log, self._name, self._parent = log, name, parent
+        self._attrs = attrs
+
+    def __enter__(self) -> Span:
+        # imported here so that repro.obs stays importable without JAX
+        from jax.profiler import TraceAnnotation
+
+        meta = {} if self._parent is None else self._parent._meta
+        span = self._log.start_span(self._name, parent=self._parent, **meta,
+                                    **self._attrs)
+        span._meta = dict(meta)
+        span._annotation = TraceAnnotation(self._name, **meta)
+        span._annotation.__enter__()
+        self._span = span
+        return span
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        span = self._span
+        if span.status is None:
+            if exc_type is None:
+                span.finish("ok")
+            else:
+                span.finish("failed", error=exc_type.__name__,
+                            phase=span.failed_child or self._name)
+        if exc_type is not None and self._parent is not None:
+            if self._parent.failed_child is None:
+                self._parent.failed_child = self._name
+        span._annotation.__exit__(None, None, None)
 
 
 def read_jsonl(path: str | os.PathLike[str]) -> list[dict[str, Any]]:

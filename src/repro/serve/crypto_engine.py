@@ -437,6 +437,10 @@ SNAPSHOT_KEYS = _STAT_KEYS + (
 _engine_names = itertools.count()
 
 
+class _StepLocal(threading.local):
+    step = None  # the traced engine.step span of this thread's dispatch
+
+
 def _bucket_key_str(cfg: api.PlanConfig) -> str:
     """Human-stable bucket label used in snapshot()['bucket_backends']
     and span attrs: enough of the PlanConfig to tell buckets apart."""
@@ -497,7 +501,10 @@ class PolymulEngine:
         ``submit()`` mints a request span (trace id on the returned
         future as ``fut.trace_id``) and the full lifecycle — admit,
         dispatch, retry, breaker transitions, terminal resolve/shed/
-        fail — lands in the log.  ``None`` (default) keeps the hot
+        fail — lands in the log; each dispatch attempt is an
+        ``engine.step`` span with one child span per phase (pack,
+        upload, launch, device_wait, readback, resolve), each also a
+        ``jax.profiler`` annotation.  ``None`` (default) keeps the hot
         paths tracing-free.
     """
 
@@ -547,6 +554,9 @@ class PolymulEngine:
         self._latencies: deque = deque(maxlen=latency_window)
         self.name = name if name is not None else f"engine-{next(_engine_names)}"
         self.span_log = span_log
+        # the engine.step span of the dispatch this thread is running, for
+        # the executor's phase spans (set only while a span_log traces)
+        self._tls = _StepLocal()
         self._registry = (
             registry if registry is not None else obs_metrics.registry()
         )
@@ -592,6 +602,9 @@ class PolymulEngine:
         # CompileFailedError it raised (so a bucket that cannot compile
         # fails fast instead of recompiling per dispatch).
         self._compiled: dict = {}
+        # PlanConfig -> the executable's shardings of (za, zb), where a
+        # traced dispatch places the operands (one device, or the mesh)
+        self._operand_shardings: dict = {}
         # The raw batch executor; fault injectors and tests wrap THIS
         # attribute (repro.serve.faults.FaultInjector.install).  Every
         # dispatch goes through it, so a wrapper sees one call per
@@ -641,6 +654,7 @@ class PolymulEngine:
             )
             try:
                 got = self._exec.lower(pl, z, z).compile()
+                self._operand_shardings[cfg] = got.input_shardings[0][1:]
             except Exception as e:  # noqa: BLE001 — any lowering/compile error
                 got = CompileFailedError(
                     f"bucket {_bucket_key_str(cfg)}: the batch executable "
@@ -810,11 +824,33 @@ class PolymulEngine:
     def _execute_batch(self, pl: api.Plan, za, zb):
         """The raw dispatch executor: one padded micro-batch through the
         plan's datapath.  ``self.executor`` points here unless a fault
-        injector (or test double) wrapped it."""
+        injector (or test double) wrapped it.
+
+        Under a traced ``engine.step`` the call is split into its four
+        operations, each a phase span: operands onto the device (with
+        the executable's input shardings), the executable and its
+        result's copy queued, the wait for the executable, the wait for
+        the copy.  Untraced it stays one call, which does the same in
+        the runtime's C++ path: the split costs ~0.25 ms of host time a
+        dispatch on a v5e host (PERF.md, Findings)."""
         if api.plan_key(pl).width == "oracle":
             # Host-only width: no tracing — runs the exact bigint path.
             return np.asarray(api.polymul(pl, za, zb))
-        return np.asarray(self._executable(pl)(pl, za, zb))
+        exe = self._executable(pl)
+        step = self._tls.step
+        if step is None:
+            return np.asarray(exe(pl, za, zb))
+        with step.child("engine.upload", bytes=za.nbytes + zb.nbytes):
+            za, zb = jax.device_put(
+                (za, zb), self._operand_shardings[api.plan_key(pl)]
+            )
+        with step.child("engine.launch"):
+            out = exe(pl, za, zb)
+            out.copy_to_host_async()  # as np.asarray does, before the wait
+        with step.child("engine.device_wait"):
+            out.block_until_ready()
+        with step.child("engine.readback", bytes=out.nbytes):
+            return np.asarray(out)
 
     def _fallback_plan(self, pl: api.Plan) -> api.Plan | None:
         """The next plan down the degradation chain for ``pl`` — same
@@ -931,7 +967,21 @@ class PolymulEngine:
         shed, or failed; 0 when idle.  A dispatch that raises never
         loses requests: the popped requests are requeued (bounded
         retries, per-bucket backoff + circuit breaking) or their futures
-        fail with a typed error."""
+        fail with a typed error.
+
+        With a ``span_log``, a call that attempts a dispatch is an
+        ``engine.step`` span from its start, with its phases as child
+        spans (:mod:`repro.obs.tracing`); a call that dispatches nothing
+        leaves no span."""
+        if self.span_log is None:
+            return self._step(None)
+        with self.span_log.traced("engine.step") as span:
+            resolved = self._step(span)
+            if span.attrs.get("dispatch_index") is None:
+                span.discard()  # idle, deferred or shed: no dispatch
+            return resolved
+
+    def _step(self, step) -> int:
         resolved = self._resolve_shed(self._collect_expired())
         while True:
             with self._cond:
@@ -965,6 +1015,14 @@ class PolymulEngine:
             with self._cond:
                 dispatch_idx = self._dispatch_seq
                 self._dispatch_seq += 1
+            pad = 0 if cfg.width == "oracle" else self.batch_slots - len(reqs)
+            if step is not None:
+                step.annotate(dispatch_index=dispatch_idx)
+                step.attrs.update(
+                    bucket=_bucket_key_str(bucket.key), batch=len(reqs),
+                    pad=pad, probing=probing,
+                    requests=[r.span.trace_id for r in reqs if r.span],
+                )
             t0 = time.perf_counter()
             for r in reqs:
                 if r.attempts == 0:  # first attempt: the queue wait
@@ -974,33 +1032,54 @@ class PolymulEngine:
                         "dispatch", dispatch_index=dispatch_idx,
                         backend=cfg.backend, batch=len(reqs),
                         attempt=r.attempts, probing=probing,
+                        step_id=None if step is None else step.trace_id,
                     )
             try:
-                if cfg.width == "oracle":
-                    za = np.stack([r.za for r in reqs])
-                    zb = np.stack([r.zb for r in reqs])
+                if step is None:
+                    za, zb = self._pack(cfg, reqs)
                     out = np.asarray(self.executor(use_plan, za, zb))
-                    pad = 0
                 else:
-                    B = self.batch_slots
-                    za = np.zeros((B, cfg.n, cfg.seg_count), np.int64)
-                    zb = np.zeros_like(za)
-                    for i, r in enumerate(reqs):
-                        za[i] = r.za
-                        zb[i] = r.zb
-                    out = np.asarray(self.executor(use_plan, za, zb))
-                    pad = B - len(reqs)
+                    with step.child("engine.pack") as phase:
+                        za, zb = self._pack(cfg, reqs)
+                        phase.attrs["bytes"] = za.nbytes + zb.nbytes
+                    self._tls.step = step
+                    try:
+                        out = np.asarray(self.executor(use_plan, za, zb))
+                    finally:
+                        self._tls.step = None
             except Exception as e:  # noqa: BLE001 — any dispatch failure
                 resolved += self._on_dispatch_failure(
                     bucket, use_plan, probing, reqs, e
                 )
+                if step is not None:
+                    step.finish("failed", error=type(e).__name__,
+                                phase=step.failed_child or step.name)
                 return resolved
             # Compilation happened before t0, so this is service time.
-            resolved += self._on_dispatch_success(
-                bucket, probing, reqs, out, pad, dispatch_idx,
-                time.perf_counter() - t0,
-            )
+            exec_s = time.perf_counter() - t0
+            if step is None:
+                resolved += self._on_dispatch_success(
+                    bucket, probing, reqs, out, pad, dispatch_idx, exec_s
+                )
+            else:
+                with step.child("engine.resolve"):
+                    resolved += self._on_dispatch_success(
+                        bucket, probing, reqs, out, pad, dispatch_idx, exec_s
+                    )
             return resolved
+
+    def _pack(self, cfg: api.PlanConfig, reqs) -> tuple:
+        """The batch's operands on the host: the requests' rows, padded
+        with zero polynomials to ``batch_slots`` (device widths)."""
+        if cfg.width == "oracle":
+            return (np.stack([r.za for r in reqs]),
+                    np.stack([r.zb for r in reqs]))
+        za = np.zeros((self.batch_slots, cfg.n, cfg.seg_count), np.int64)
+        zb = np.zeros_like(za)
+        for i, r in enumerate(reqs):
+            za[i] = r.za
+            zb[i] = r.zb
+        return za, zb
 
     def _on_dispatch_success(self, bucket, probing, reqs, out, pad,
                              dispatch_idx, exec_s) -> int:
