@@ -437,6 +437,14 @@ SNAPSHOT_KEYS = _STAT_KEYS + (
 _engine_names = itertools.count()
 
 
+def _result_dtype(cfg: api.PlanConfig):
+    """The dtype the batch executable's result crosses to the host in:
+    int32 while a base-2^w limb fits a signed 32-bit word (every width
+    today: w = 28), else int64.  An int64 array reads back from a v5e
+    at ~0.2 GB/s, an int32 one 13–22x faster (PERF.md, Findings)."""
+    return jnp.int32 if cfg.w <= 31 else jnp.int64
+
+
 class _StepLocal(threading.local):
     step = None  # the traced engine.step span of this thread's dispatch
 
@@ -586,14 +594,26 @@ class PolymulEngine:
             "repro_engine_inflight", "popped, dispatch outcome pending",
             ("engine",),
         ).labels(engine=self.name)
+        # Outside _STAT_KEYS, so snapshot()'s schema does not change.
+        self._m_packed_readbacks = self._registry.counter(
+            "repro_engine_packed_readbacks_total",
+            "executor results read back from the device as int32",
+            ("engine",),
+        ).labels(engine=self.name)
 
         def _run(pl, za, zb):
+            """The batch executable: ``(B, n, S)`` segments -> ``(B, n,
+            L)`` product limbs in :func:`_result_dtype`;
+            ``_execute_batch`` widens them back to int64 on the host."""
             # Appended at TRACE time only: the probe that asserts one
             # compilation per distinct PlanConfig.
             self._trace_log.append(api.plan_key(pl))
             if mesh is not None:
-                return polymul_sharded(pl, za, zb, mesh=mesh)
-            return api.polymul(pl, za, zb)
+                out = polymul_sharded(pl, za, zb, mesh=mesh)
+            else:
+                out = api.polymul(pl, za, zb)
+            with jax.named_scope("engine.result_narrow"):
+                return out.astype(_result_dtype(pl.config))
 
         self._exec = jax.jit(
             _run, donate_argnums=(1, 2) if donate else ()
@@ -645,7 +665,8 @@ class PolymulEngine:
 
     def _executable(self, pl: api.Plan):
         """``pl``'s compiled batch executable, compiled on first use for
-        the padded ``(batch_slots, n, S)`` operand shape."""
+        the padded ``(batch_slots, n, S)`` operand shape; its result is
+        ``(batch_slots, n, L)`` in :func:`_result_dtype`."""
         cfg = api.plan_key(pl)
         got = self._compiled.get(cfg)
         if got is None:
@@ -823,34 +844,44 @@ class PolymulEngine:
     # -- execution -----------------------------------------------------
     def _execute_batch(self, pl: api.Plan, za, zb):
         """The raw dispatch executor: one padded micro-batch through the
-        plan's datapath.  ``self.executor`` points here unless a fault
+        plan's datapath, returned as one C-contiguous ``(batch_slots, n,
+        L)`` int64 array.  ``self.executor`` points here unless a fault
         injector (or test double) wrapped it.
+
+        The result crosses to the host as int32 (:func:`_result_dtype`)
+        and is widened to int64 here.  On a v5e the device already holds
+        it lane-dense, n on the 128 lanes, whatever its logical axis
+        order: XLA picks that layout for the executable's result.
 
         Under a traced ``engine.step`` the call is split into its four
         operations, each a phase span: operands onto the device (with
         the executable's input shardings), the executable and its
         result's copy queued, the wait for the executable, the wait for
-        the copy.  Untraced it stays one call, which does the same in
-        the runtime's C++ path: the split costs ~0.25 ms of host time a
-        dispatch on a v5e host (PERF.md, Findings)."""
-        if api.plan_key(pl).width == "oracle":
+        the copy and the widening (the span's ``bytes`` are the device
+        bytes read back).  Untraced it stays one call, which does
+        the same in the runtime's C++ path: the split costs ~0.25 ms of
+        host time a dispatch on a v5e host (PERF.md, Findings)."""
+        cfg = api.plan_key(pl)
+        if cfg.width == "oracle":
             # Host-only width: no tracing — runs the exact bigint path.
             return np.asarray(api.polymul(pl, za, zb))
         exe = self._executable(pl)
         step = self._tls.step
         if step is None:
-            return np.asarray(exe(pl, za, zb))
-        with step.child("engine.upload", bytes=za.nbytes + zb.nbytes):
-            za, zb = jax.device_put(
-                (za, zb), self._operand_shardings[api.plan_key(pl)]
-            )
-        with step.child("engine.launch"):
-            out = exe(pl, za, zb)
-            out.copy_to_host_async()  # as np.asarray does, before the wait
-        with step.child("engine.device_wait"):
-            out.block_until_ready()
-        with step.child("engine.readback", bytes=out.nbytes):
-            return np.asarray(out)
+            limbs = np.asarray(exe(pl, za, zb), np.int64)
+        else:
+            with step.child("engine.upload", bytes=za.nbytes + zb.nbytes):
+                za, zb = jax.device_put((za, zb), self._operand_shardings[cfg])
+            with step.child("engine.launch"):
+                out = exe(pl, za, zb)
+                out.copy_to_host_async()  # as np.asarray does, before the wait
+            with step.child("engine.device_wait"):
+                out.block_until_ready()
+            with step.child("engine.readback", bytes=out.nbytes):
+                limbs = np.asarray(out, np.int64)
+        if _result_dtype(cfg) == jnp.int32:
+            self._m_packed_readbacks.inc()
+        return limbs
 
     def _fallback_plan(self, pl: api.Plan) -> api.Plan | None:
         """The next plan down the degradation chain for ``pl`` — same
@@ -1355,6 +1386,7 @@ class PolymulEngine:
         with self._cond:
             for c in self._m.values():
                 c.reset()
+            self._m_packed_readbacks.reset()
             self._h_latency.reset()
             self._h_queue_wait.reset()
             self._latencies.clear()

@@ -454,7 +454,7 @@ def test_each_dispatch_is_a_step_with_six_phases_in_order():
         n, S = 64, eng.plan(n=64, t=3, v=30).config.seg_count
         L = futs[0].result().shape[1]
         assert pack["attrs"]["bytes"] == upload["attrs"]["bytes"] == 2 * 4 * n * S * 8
-        assert readback["attrs"]["bytes"] == 4 * n * L * 8  # (slots, n, L) int64
+        assert readback["attrs"]["bytes"] == 4 * n * L * 4  # (slots, n, L) int32
         # no step or phase span carries a dispatch event
         assert st["events"] == [] and all(c["events"] == [] for c in children)
 

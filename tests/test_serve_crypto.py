@@ -13,8 +13,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 import repro
-from repro import api
+from repro import api, obs
 from repro.core import polymul as pm
+from repro.obs.metrics import MetricsRegistry
 from repro.serve.crypto_engine import (
     PolymulEngine,
     negacyclic_mul_sharded,
@@ -160,6 +161,71 @@ class TestEngineBatching:
             pl, jnp.asarray(za), jnp.asarray(zb), donate=True
         )
         assert np.array_equal(np.asarray(got_d), want)
+
+
+def _packed_readbacks(eng) -> float:
+    return eng._registry.get("repro_engine_packed_readbacks_total").labels(
+        engine=eng.name
+    ).value
+
+
+# (n, t, v) of one small plan per compiled width
+_WIDTH_PLANS = [
+    pytest.param((64, 3, 30), id="int64"),
+    pytest.param((32, 4, 45), id="wide"),
+]
+
+
+class TestInt32Readback:
+    """The batch executable hands its result to the host as int32, and
+    the executor widens it back to the ``(batch_slots, n, L)`` int64
+    contract on the host."""
+
+    @pytest.mark.parametrize("traced", [False, True],
+                             ids=["untraced", "traced"])
+    @pytest.mark.parametrize("ntv", _WIDTH_PLANS)
+    def test_result_crosses_as_int32_and_is_widened(self, ntv, traced):
+        n, t, v = ntv
+        eng = PolymulEngine(
+            batch_slots=4, registry=MetricsRegistry(),
+            span_log=obs.SpanLog(None) if traced else None,
+        )
+        pl = eng.plan(n=n, t=t, v=v)
+        L = pl.config.L
+        rng = np.random.default_rng(14)
+        za, zb = _rand_segments(pl, rng, batch=4)
+        dev = eng._executable(pl)(pl, za, zb)
+        assert dev.shape == (4, n, L) and dev.dtype == jnp.int32
+        seen = []
+        inner = eng.executor
+
+        def spy(p, a, b):
+            out = inner(p, a, b)
+            seen.append((a, b, out))
+            return out
+
+        eng.executor = spy
+        futs = [eng.submit(pl, *_rand_segments(pl, rng)) for _ in range(6)]
+        eng.run_until_idle()
+        assert len(seen) == eng.stats["dispatches"] == 2  # 4 + 2 padded
+        for a, b, out in seen:
+            assert out.shape == (4, n, L) and out.dtype == np.int64
+            assert out.flags.c_contiguous
+            want = np.asarray(api.polymul(pl, jnp.asarray(a), jnp.asarray(b)))
+            assert np.array_equal(out, want)
+        assert np.array_equal(futs[5].result(), seen[1][2][1])
+        assert _packed_readbacks(eng) == eng.stats["dispatches"]
+
+    def test_traced_readback_bytes_are_the_int32_result(self):
+        log = obs.SpanLog(None)
+        eng = PolymulEngine(batch_slots=4, registry=MetricsRegistry(),
+                            span_log=log)
+        pl = eng.plan(n=64, t=3, v=30)
+        eng.submit(pl, *_rand_segments(pl, np.random.default_rng(15)))
+        eng.run_until_idle()
+        (readback,) = [s for s in log.spans() if s["name"] == "engine.readback"]
+        assert readback["attrs"]["bytes"] == 4 * 64 * pl.config.L * 4  # int32
+        assert _packed_readbacks(eng) == 1
 
 
 class TestCryptoPartitionRules:

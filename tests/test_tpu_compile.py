@@ -12,6 +12,7 @@ compilation cache is off while these compiles run, because an entry
 compiled for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ import repro
 from repro import api
 from repro.errors import PlanError, UnservableConfigError
 from repro.kernels import ops
+from repro.serve.crypto_engine import PolymulEngine
 
 
 @pytest.fixture(scope="module")
@@ -49,14 +51,15 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_polymul(pl, sharding, rows):
-    """AOT-compile ``repro.polymul`` for ``rows`` products of ``pl`` with
-    every leaf and operand placed on ``sharding``."""
+def _compile_polymul(pl, sharding, rows, fn=api.polymul):
+    """AOT-compile ``fn`` (default ``repro.polymul``) for ``rows``
+    products of ``pl`` with every leaf and operand placed on
+    ``sharding``."""
     on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
     z = jax.ShapeDtypeStruct(
         (rows, pl.n, pl.config.seg_count), jnp.int64, sharding=sharding
     )
-    return jax.jit(api.polymul).lower(jax.tree.map(on_chip, pl), z, z).compile()
+    return jax.jit(fn).lower(jax.tree.map(on_chip, pl), z, z).compile()
 
 
 def test_served_jnp_path_compiles_for_one_chip(one_chip):
@@ -71,6 +74,24 @@ def test_served_jnp_path_compiles_for_one_chip(one_chip):
     for stage in ("decompose", "cascade", "compose"):
         assert f"parentt.{stage}" in hlo, stage
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_engine_result_is_int32_with_n_on_the_lanes(one_chip):
+    """The engine's batch executable, compiled for one v5e, returns its
+    result as int32 with n, not the L limbs, on the minor (lane) axis
+    of the device layout: an int64 result read back 13–22x slower."""
+    eng = PolymulEngine(batch_slots=8)
+    pl = eng.plan(n=16, t=2, v=30)
+    compiled = _compile_polymul(pl, one_chip, rows=8, fn=eng._exec)
+    header = compiled.as_text().splitlines()[0]
+    result = re.search(
+        r"entry_computation_layout=\{.*\)->(\w+)\[([\d,]+)\]\{([\d,]+)", header
+    )
+    assert result, header
+    dtype, dims, minor_to_major = result.groups()
+    assert dtype == "s32"
+    assert dims == f"8,16,{pl.config.L}"  # (batch_slots, n, L)
+    assert minor_to_major.split(",")[0] == "1"  # n is the minor axis
 
 
 @pytest.mark.parametrize("backend", ops.PALLAS_BACKENDS)
