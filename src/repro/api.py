@@ -736,13 +736,27 @@ def polymul(pl: Plan, za: Any, zb: Any) -> jax.Array:
             f"{tuple(zb.shape)}"
         )
     if cfg.width == "wide":
-        ra = _wide_decompose(pl, za)
-        rb = _wide_decompose(pl, zb)
-        specs = _wide_exec_specs(pl)
-        rp = wide_mod.negacyclic_mul_channels(
-            ra, rb, pl.consts["wide_fwd"], pl.consts["wide_inv"], specs
-        )
-        return _wide_compose(pl, rp)
+        from jax.experimental.xla_metadata import set_xla_metadata
+
+        # The int64 branch's stage scopes, so that a device trace
+        # attributes the wide datapath's operations the same way.  Scopes
+        # are debug info, which the persistent compile cache strips from
+        # its key: the width as an op attribute keys this program apart
+        # from the same arithmetic lowered without the scopes, whose
+        # cached executable names no stage.
+        with set_xla_metadata(parentt_width="wide"), \
+                jax.named_scope("parentt.polymul"):
+            with jax.named_scope("parentt.decompose"):
+                ra = _wide_decompose(pl, za)
+                rb = _wide_decompose(pl, zb)
+            specs = _wide_exec_specs(pl)
+            with jax.named_scope("parentt.cascade"):
+                rp = wide_mod.negacyclic_mul_channels(
+                    ra, rb, pl.consts["wide_fwd"], pl.consts["wide_inv"],
+                    specs,
+                )
+            with jax.named_scope("parentt.compose"):
+                return _wide_compose(pl, rp)
     return _oracle_polymul(pl, za, zb)
 
 

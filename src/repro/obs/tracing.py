@@ -181,10 +181,11 @@ class SpanLog:
         # one wall/monotonic anchor pair; every record's t_unix derives
         # from it instead of a per-record time.time() call.  The wall
         # reading is bracketed by two perf_counter readings and paired
-        # with their midpoint.
-        t0 = time.perf_counter()
+        # with their midpoint, a whole number of ns (see to_trace_ns).
+        t0 = time.perf_counter_ns()
         self._anchor_ns = time.time_ns()
-        self._anchor_perf = (t0 + time.perf_counter()) / 2
+        self._anchor_perf_ns = (t0 + time.perf_counter_ns()) // 2
+        self._anchor_perf = self._anchor_perf_ns / 1e9
         self._lock = threading.Lock()
         self._records: list[dict[str, Any]] = []
         self._unflushed: list[dict[str, Any]] = []
@@ -199,8 +200,15 @@ class SpanLog:
         integer ns since the Unix epoch, where an ``.xplane.pb`` event
         lies at ``profile_start_time + start_ns`` (module docstring).
         Both clocks tick in step, so the mapping is the anchor's one
-        offset; PERF.md gives its residual against a chip trace."""
-        return self._anchor_ns + round((t_perf - self._anchor_perf) * 1e9)
+        offset; PERF.md gives its residual against a chip trace.
+
+        The offset is a whole number of ns and ``t_perf`` is rounded to
+        the ns from its exact binary value, so two times a whole number
+        of ns apart map exactly that far apart; with a half-ns offset or
+        a float product, float noise would decide the rounding."""
+        num, den = t_perf.as_integer_ratio()
+        t_ns = (2 * num * 10**9 + den) // (2 * den)  # round half up
+        return self._anchor_ns - self._anchor_perf_ns + t_ns
 
     # -- recording -----------------------------------------------------
     def start_span(self, name: str, *, parent: Span | None = None,

@@ -618,6 +618,13 @@ class PolymulEngine:
         self._exec = jax.jit(
             _run, donate_argnums=(1, 2) if donate else ()
         )
+        # Successful dispatches by the plan's datapath width; also
+        # outside _STAT_KEYS.  Children are made on first use.
+        self._m_width_dispatches = self._registry.counter(
+            "repro_engine_width_dispatches_total",
+            "successful executor calls by datapath width",
+            ("engine", "width"),
+        )
         # PlanConfig -> its compiled batch executable, or the
         # CompileFailedError it raised (so a bucket that cannot compile
         # fails fast instead of recompiling per dispatch).
@@ -1050,7 +1057,8 @@ class PolymulEngine:
             if step is not None:
                 step.annotate(dispatch_index=dispatch_idx)
                 step.attrs.update(
-                    bucket=_bucket_key_str(bucket.key), batch=len(reqs),
+                    bucket=_bucket_key_str(bucket.key), width=cfg.width,
+                    batch=len(reqs),
                     pad=pad, probing=probing,
                     requests=[r.span.trace_id for r in reqs if r.span],
                 )
@@ -1140,6 +1148,9 @@ class PolymulEngine:
                 else 0.75 * bucket.ewma_service_s + 0.25 * exec_s
             )
             self._m["dispatches"].inc()
+            self._m_width_dispatches.labels(
+                engine=self.name, width=bucket.key.width
+            ).inc()
             self._m["served"].inc(len(reqs))
             self._m["padded_slots"].inc(pad)
             for r in reqs:
@@ -1387,6 +1398,9 @@ class PolymulEngine:
             for c in self._m.values():
                 c.reset()
             self._m_packed_readbacks.reset()
+            for (engine, _), child in self._m_width_dispatches.children():
+                if engine == self.name:
+                    child.reset()
             self._h_latency.reset()
             self._h_queue_wait.reset()
             self._latencies.clear()

@@ -459,6 +459,60 @@ def test_each_dispatch_is_a_step_with_six_phases_in_order():
         assert st["events"] == [] and all(c["events"] == [] for c in children)
 
 
+def _width_dispatches(reg, eng):
+    fam = reg.get("repro_engine_width_dispatches_total")
+    return {width: child.value for (engine, width), child in fam.children()
+            if engine == eng.name}
+
+
+def test_dispatches_by_width_sum_to_dispatches_and_steps_name_the_width():
+    log, reg = obs.SpanLog(None), MetricsRegistry()
+    eng = PolymulEngine(batch_slots=4, span_log=log, registry=reg)
+    rng = np.random.default_rng(15)
+    plans = {"int64": eng.plan(n=64, t=3, v=30), "wide": eng.plan(n=32, t=4, v=45)}
+    for pl, count in ((plans["int64"], 6), (plans["wide"], 3)):
+        shape = (pl.n, pl.config.seg_count)
+        for _ in range(count):
+            eng.submit(pl, rng.integers(0, 1 << pl.v, size=shape),
+                       rng.integers(0, 1 << pl.v, size=shape))
+    eng.run_until_idle()
+    by_width = _width_dispatches(reg, eng)
+    assert by_width == {"int64": 2, "wide": 1}  # 4 + 2 padded, 3 padded
+    assert sum(by_width.values()) == eng.stats["dispatches"] == 3
+    steps = _steps(log)
+    assert len(steps) == 3
+    for st, children in steps:
+        width = st["attrs"]["width"]
+        cfg = plans[width].config
+        assert st["attrs"]["bucket"] == f"n{cfg.n}_t{cfg.t}_v{cfg.v}_{cfg.backend}"
+        _assert_phases_nested_in_order(st, children)
+    assert sorted(st["attrs"]["width"] for st, _ in steps) == ["int64", "int64", "wide"]
+    eng.reset_stats()
+    assert _width_dispatches(reg, eng) == {"int64": 0, "wide": 0}
+
+
+@pytest.mark.parametrize("ntv", [(64, 3, 30), (32, 4, 45)], ids=["int64", "wide"])
+def test_both_device_widths_carry_the_stage_scopes(ntv):
+    """The compiled polymul names its pipeline stages in its op metadata
+    on both device widths, which is how a device trace attributes them."""
+    import re
+
+    import jax
+
+    from repro import api
+
+    n, t, v = ntv
+    pl = api.plan(n=n, t=t, v=v)
+    z = np.zeros((2, n, pl.config.seg_count), np.int64)
+    hlo = jax.jit(api.polymul).lower(pl, z, z).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    scopes = {m for name in op_names for m in re.findall(r"parentt\.(\w+)", name)}
+    assert {"polymul", "decompose", "cascade", "compose"} <= scopes
+    assert "fused_e2e" not in scopes
+    # every stage nests under the one root
+    assert all("parentt.polymul/" in name for name in op_names if "parentt." in name)
+
+
 def test_request_dispatch_events_point_at_their_step():
     log = obs.SpanLog(None)
     eng, futs = _drive_engine(span_log=log)
@@ -590,6 +644,19 @@ def test_mesh_mode_dispatches_are_traced(host_mesh_4):
     assert len(steps) == 2
     for st, kids in steps:
         _assert_phases_nested_in_order(st, kids)
+
+
+def test_span_log_clock_maps_whole_ns_apart_exactly():
+    """perf_counter readings are whole ns; however the anchor and the
+    readings fall, two of them d ns apart map exactly d ns apart."""
+    rng = np.random.default_rng(16)
+    for _ in range(20):
+        log = obs.SpanLog(None)
+        for n_ns in rng.integers(10**9, 2**21 * 10**9, size=200, dtype=np.int64):
+            t = int(n_ns) / 1e9
+            for d in (1, 999_999_999, 2_500_000_000):
+                assert log.to_trace_ns((int(n_ns) + d) / 1e9) - log.to_trace_ns(t) == d
+            assert log.to_trace_ns(t + 2.5) - log.to_trace_ns(t) == 2_500_000_000
 
 
 @pytest.mark.parametrize("reference", ["wall clock", "profiler trace"])

@@ -169,10 +169,32 @@ def _packed_readbacks(eng) -> float:
     ).value
 
 
+def _schoolbook_mod_q(pl, za, zb):
+    """Plain Python negacyclic product of two segment polynomials, mod
+    q, as base-2^w limbs: the check owes nothing to the program."""
+    v, w, n, q = pl.v, pl.config.w, pl.n, pl.q
+
+    def value(segs):
+        return sum(int(d) << (v * j) for j, d in enumerate(segs))
+
+    a = [value(r) for r in za]
+    b = [value(r) for r in zb]
+    c = [0] * n
+    for i in range(n):
+        for j in range(n):
+            if i + j < n:
+                c[i + j] += a[i] * b[j]
+            else:
+                c[i + j - n] -= a[i] * b[j]
+    mask = (1 << w) - 1
+    return np.array([[((x % q) >> (w * k)) & mask for k in range(pl.config.L)]
+                     for x in c], np.int64)
+
+
 # (n, t, v) of one small plan per compiled width
 _WIDTH_PLANS = [
     pytest.param((64, 3, 30), id="int64"),
-    pytest.param((32, 4, 45), id="wide"),
+    pytest.param((64, 4, 45), id="wide"),
 ]
 
 
@@ -205,7 +227,8 @@ class TestInt32Readback:
             return out
 
         eng.executor = spy
-        futs = [eng.submit(pl, *_rand_segments(pl, rng)) for _ in range(6)]
+        operands = [_rand_segments(pl, rng) for _ in range(6)]
+        futs = [eng.submit(pl, a, b) for a, b in operands]
         eng.run_until_idle()
         assert len(seen) == eng.stats["dispatches"] == 2  # 4 + 2 padded
         for a, b, out in seen:
@@ -214,6 +237,9 @@ class TestInt32Readback:
             want = np.asarray(api.polymul(pl, jnp.asarray(a), jnp.asarray(b)))
             assert np.array_equal(out, want)
         assert np.array_equal(futs[5].result(), seen[1][2][1])
+        # a full batch and a partial one, each product exact mod q
+        for fut, (a, b) in zip(futs, operands):
+            assert np.array_equal(fut.result(), _schoolbook_mod_q(pl, a, b))
         assert _packed_readbacks(eng) == eng.stats["dispatches"]
 
     def test_traced_readback_bytes_are_the_int32_result(self):
