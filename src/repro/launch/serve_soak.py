@@ -107,11 +107,11 @@ def run_soak(*, requests: int = 520, seed: int = 0, batch_slots: int = 8,
     )
     plans = [eng.plan(**c) for c in CONFIGS]
 
-    # The injector installs before ANY dispatch, so its call counter and
-    # the engine's dispatch_index stamps advance in lock-step — the
-    # corruption join below depends on that 1:1 alignment.  Compilation
-    # therefore happens inside the faulted run; the soak deadlines are
-    # sized to absorb it.
+    # The injector indexes each executor call by the engine's
+    # dispatch_index for it, the stamp on the futures it resolves — the
+    # corruption join below depends on that 1:1 alignment.  It installs
+    # before any dispatch, so compilation happens inside the faulted run;
+    # the soak deadlines are sized to absorb it.
     inj = FaultInjector(
         rules if rules is not None else default_rules(breaker_threshold),
         seed=seed,

@@ -53,11 +53,16 @@ both halves:
   raises :class:`repro.errors.CompileFailedError` from ``warmup`` and
   fails the bucket's requests with it, and the bucket serves nothing
   else in its place.
-* **Async front end.**  ``start()`` launches a background dispatcher
-  thread driving :meth:`PolymulEngine.step`; submission then overlaps
-  host batching with device execution and futures support
-  ``result(timeout=)`` blocking waits.  The synchronous
-  ``step()``/``run_until_idle()`` closed loop keeps working unchanged.
+* **Async front end.**  ``start()`` launches two background
+  dispatcher workers driving :meth:`PolymulEngine.step`, so up to two
+  dispatches are outstanding: one batch is packed, uploaded and
+  launched, or read back and resolved, while the other runs on the
+  device.  A second dispatch starts only on a full, healthy bucket
+  (:meth:`PolymulEngine._select_locked`), so an open mix at a moderate
+  rate and a degraded bucket dispatch one batch at a time, as before.
+  Futures support ``result(timeout=)`` blocking waits.  The synchronous
+  ``step()``/``run_until_idle()`` closed loop keeps one dispatch per
+  call.
 * **Mesh mode.**  With ``mesh=``, dispatches run
   :func:`polymul_sharded`: the RNS channel axis of the residue cascade
   shard_maps over ``model`` and the batch axis over ``data``, with the
@@ -447,6 +452,7 @@ def _result_dtype(cfg: api.PlanConfig):
 
 class _StepLocal(threading.local):
     step = None  # the traced engine.step span of this thread's dispatch
+    dispatch_index = None  # the dispatch whose executor call this thread runs
 
 
 def _bucket_key_str(cfg: api.PlanConfig) -> str:
@@ -464,7 +470,7 @@ class PolymulEngine:
     """Shape-bucketed continuous-batching engine over the Plan API, with
     deadline/priority scheduling, bounded-queue backpressure, bounded
     retry, per-bucket circuit breaking onto fallback backends, and an
-    optional background dispatcher thread (see module docstring).
+    optional two-worker background dispatcher (see module docstring).
 
     Parameters
     ----------
@@ -516,6 +522,10 @@ class PolymulEngine:
         paths tracing-free.
     """
 
+    # Background dispatcher workers, hence dispatches outstanding at once
+    # under start(): a second starts only where _select_locked allows it.
+    _WORKERS = 2
+
     def __init__(self, *, batch_slots: int = 8, mesh=None,
                  donate: bool = False, max_pending: int | None = None,
                  max_retries: int = 3, breaker_threshold: int = 3,
@@ -554,7 +564,8 @@ class PolymulEngine:
         self._seq = itertools.count()
         self._trace_log: list[api.PlanConfig] = []
         self._cond = threading.Condition()
-        self._thread: threading.Thread | None = None
+        self._threads: list[threading.Thread] = []  # the running workers
+        self._compile_lock = threading.Lock()
         self._stop_evt = threading.Event()
         self._loop_error: BaseException | None = None
         self._inflight = 0
@@ -600,6 +611,11 @@ class PolymulEngine:
             "executor results read back from the device as int32",
             ("engine",),
         ).labels(engine=self.name)
+        self._m_overlapped = self._registry.counter(
+            "repro_engine_overlapped_dispatches_total",
+            "executor calls begun while another dispatch was outstanding",
+            ("engine",),
+        ).labels(engine=self.name)
 
         def _run(pl, za, zb):
             """The batch executable: ``(B, n, S)`` segments -> ``(B, n,
@@ -632,10 +648,13 @@ class PolymulEngine:
         # PlanConfig -> the executable's shardings of (za, zb), where a
         # traced dispatch places the operands (one device, or the mesh)
         self._operand_shardings: dict = {}
-        # The raw batch executor; fault injectors and tests wrap THIS
-        # attribute (repro.serve.faults.FaultInjector.install).  Every
-        # dispatch goes through it, so a wrapper sees one call per
-        # engine dispatch attempt in dispatch-index order.
+        # The raw batch executor, called synchronously: one padded batch
+        # in, its (batch_slots, n, L) int64 result out.  Fault injectors
+        # and tests wrap THIS attribute (FaultInjector.install).  Every
+        # dispatch goes through it, so a wrapper sees one call per engine
+        # dispatch attempt; under start() two workers may call it at
+        # once, and out of dispatch-index order, so the index of the
+        # call in progress is on the calling thread's ``_tls``.
         self.executor = self._execute_batch
 
     # -- plan cache ----------------------------------------------------
@@ -677,23 +696,29 @@ class PolymulEngine:
         cfg = api.plan_key(pl)
         got = self._compiled.get(cfg)
         if got is None:
-            z = jax.ShapeDtypeStruct(
-                (self.batch_slots, cfg.n, cfg.seg_count), jnp.int64
-            )
-            try:
-                got = self._exec.lower(pl, z, z).compile()
-                self._operand_shardings[cfg] = got.input_shardings[0][1:]
-            except Exception as e:  # noqa: BLE001 — any lowering/compile error
-                got = CompileFailedError(
-                    f"bucket {_bucket_key_str(cfg)}: the batch executable "
-                    f"did not compile for {jax.devices()[0].platform}: "
-                    f"{type(e).__name__}: {e}",
-                    backend=cfg.backend,
-                )
-                got.__cause__ = e
-            self._compiled[cfg] = got
+            with self._compile_lock:  # two workers that miss compile once
+                got = self._compiled.get(cfg)
+                if got is None:
+                    got = self._compiled[cfg] = self._compile(pl, cfg)
         if isinstance(got, CompileFailedError):
             raise got
+        return got
+
+    def _compile(self, pl: api.Plan, cfg: api.PlanConfig):
+        z = jax.ShapeDtypeStruct(
+            (self.batch_slots, cfg.n, cfg.seg_count), jnp.int64
+        )
+        try:
+            got = self._exec.lower(pl, z, z).compile()
+            self._operand_shardings[cfg] = got.input_shardings[0][1:]
+        except Exception as e:  # noqa: BLE001 — any lowering/compile error
+            got = CompileFailedError(
+                f"bucket {_bucket_key_str(cfg)}: the batch executable "
+                f"did not compile for {jax.devices()[0].platform}: "
+                f"{type(e).__name__}: {e}",
+                backend=cfg.backend,
+            )
+            got.__cause__ = e
         return got
 
     # -- request intake ------------------------------------------------
@@ -734,7 +759,7 @@ class PolymulEngine:
                 key=cfg, chain=[self._plans.setdefault(cfg, pl)]
             )
         fut = PolymulFuture()
-        fut._async = self._thread is not None
+        fut._async = bool(self._threads)
         req = _Request(
             za=za, zb=zb, future=fut, seq=next(self._seq), t_submit=now,
             deadline=(now + deadline) if deadline is not None else None,
@@ -853,7 +878,10 @@ class PolymulEngine:
         """The raw dispatch executor: one padded micro-batch through the
         plan's datapath, returned as one C-contiguous ``(batch_slots, n,
         L)`` int64 array.  ``self.executor`` points here unless a fault
-        injector (or test double) wrapped it.
+        injector (or test double) wrapped it.  The call is synchronous:
+        it returns once the result is on the host.  The background
+        dispatcher overlaps two batches by calling it from two workers,
+        never by splitting it.
 
         The result crosses to the host as int32 (:func:`_result_dtype`)
         and is widened to int64 here.  On a v5e the device already holds
@@ -954,31 +982,43 @@ class PolymulEngine:
         """EDF bucket choice: among buckets whose backoff gate is open,
         pick the one whose head request sorts first by
         (deadline, -priority, seq).  Returns ``None`` (idle),
-        ``("defer", wake_at)`` (all live buckets backing off) or
-        ``("go", bucket, plan_to_use, probing)``."""
+        ``("defer", wake_at)`` (all live buckets backing off),
+        ``("hold",)`` (another dispatch is outstanding and this one may
+        not start beside it) or ``("go", bucket, plan_to_use, probing)``.
+        Changes nothing, so the dispatcher's idle test calls it too.
+
+        A second dispatch starts beside an outstanding one only on a
+        bucket that fills a whole batch and is healthy: not degraded,
+        not failing (so not backing off) and so not probing either.  An
+        open mix at a moderate rate thus keeps one partial batch at a
+        time, and the breaker one probe or degraded dispatch."""
         live = [b for b in self._buckets.values() if b.heap]
         if not live:
             return None
         ready = [b for b in live if b.not_before <= now]
-        if not ready:
+        bucket = min(ready, key=lambda b: b.heap[0][:3]) if ready else None
+        if self._inflight and (
+            bucket is None or len(bucket.heap) < self.batch_slots
+            or bucket.level > 0 or bucket.failures > 0
+        ):
+            return ("hold",)
+        if bucket is None:
             return ("defer", min(b.not_before for b in live))
-        bucket = min(ready, key=lambda b: b.heap[0][:3])
         probing = (
             bucket.level > 0
             and now - bucket.opened_at >= self.breaker_cooldown_s
         )
-        if probing:
-            self._m["probes"].inc()
-            if self.span_log is not None:
-                self.span_log.event(
-                    "probe", engine=self.name,
-                    bucket=_bucket_key_str(bucket.key),
-                    backend=api.plan_key(bucket.chain[0]).backend,
-                )
-            use_plan = bucket.chain[0]
-        else:
-            use_plan = bucket.active_plan
+        use_plan = bucket.chain[0] if probing else bucket.active_plan
         return ("go", bucket, use_plan, probing)
+
+    def _count_probe_locked(self, bucket: _Bucket) -> None:
+        self._m["probes"].inc()
+        if self.span_log is not None:
+            self.span_log.event(
+                "probe", engine=self.name,
+                bucket=_bucket_key_str(bucket.key),
+                backend=api.plan_key(bucket.chain[0]).backend,
+            )
 
     def _admit_locked(self, bucket: _Bucket):
         """Pop up to ``batch_slots`` requests, shedding any whose
@@ -1005,7 +1045,9 @@ class PolymulEngine:
         shed, or failed; 0 when idle.  A dispatch that raises never
         loses requests: the popped requests are requeued (bounded
         retries, per-bucket backoff + circuit breaking) or their futures
-        fail with a typed error.
+        fail with a typed error.  Called directly, each call runs at
+        most one dispatch; the background dispatcher (:meth:`start`)
+        calls it from two workers, so that two may be outstanding.
 
         With a ``span_log``, a call that attempts a dispatch is an
         ``engine.step`` span from its start, with its phases as child
@@ -1016,15 +1058,21 @@ class PolymulEngine:
         with self.span_log.traced("engine.step") as span:
             resolved = self._step(span)
             if span.attrs.get("dispatch_index") is None:
-                span.discard()  # idle, deferred or shed: no dispatch
+                span.discard()  # idle, deferred, held or shed: no dispatch
             return resolved
 
     def _step(self, step) -> int:
         resolved = self._resolve_shed(self._collect_expired())
         while True:
-            with self._cond:
+            with self._cond:  # one hold: a second worker sees _inflight
                 pick = self._select_locked(time.perf_counter())
-            if pick is None:
+                if pick is not None and pick[0] == "go":
+                    _, bucket, use_plan, probing = pick
+                    if probing:
+                        self._count_probe_locked(bucket)
+                    reqs, shed = self._admit_locked(bucket)
+                    self._inflight += len(reqs)
+            if pick is None or pick[0] == "hold":
                 return resolved
             if pick[0] == "defer":
                 if self._stop_evt.is_set():
@@ -1034,11 +1082,6 @@ class PolymulEngine:
                 )
                 resolved += self._resolve_shed(self._collect_expired())
                 continue
-            _, bucket, use_plan, probing = pick
-            with self._cond:
-                reqs, shed = self._admit_locked(bucket)
-                if reqs:
-                    self._inflight += len(reqs)
             resolved += self._resolve_shed(shed)
             if not reqs:
                 continue  # everything admitted this round was shed
@@ -1076,16 +1119,21 @@ class PolymulEngine:
             try:
                 if step is None:
                     za, zb = self._pack(cfg, reqs)
-                    out = np.asarray(self.executor(use_plan, za, zb))
                 else:
                     with step.child("engine.pack") as phase:
                         za, zb = self._pack(cfg, reqs)
                         phase.attrs["bytes"] = za.nbytes + zb.nbytes
-                    self._tls.step = step
-                    try:
-                        out = np.asarray(self.executor(use_plan, za, zb))
-                    finally:
-                        self._tls.step = None
+                with self._cond:  # another dispatch's requests in flight
+                    overlapped = self._inflight > len(reqs)
+                if overlapped:
+                    self._m_overlapped.inc()
+                if step is not None:
+                    step.attrs["overlapped"] = overlapped
+                self._tls.step, self._tls.dispatch_index = step, dispatch_idx
+                try:
+                    out = np.asarray(self.executor(use_plan, za, zb))
+                finally:
+                    self._tls.step = self._tls.dispatch_index = None
             except Exception as e:  # noqa: BLE001 — any dispatch failure
                 resolved += self._on_dispatch_failure(
                     bucket, use_plan, probing, reqs, e
@@ -1264,7 +1312,7 @@ class PolymulEngine:
         and returns the number of requests resolved.  With the
         background dispatcher running: blocks until the queue and
         in-flight work drain (returns 0; see ``stats``)."""
-        if self._thread is not None:
+        if self._threads:
             with self._cond:
                 while self._has_work_locked():
                     if self._loop_error is not None:
@@ -1289,52 +1337,66 @@ class PolymulEngine:
 
     # -- async front end -----------------------------------------------
     def start(self) -> "PolymulEngine":
-        """Launch the background dispatcher thread (idempotent).  While
+        """Launch the background dispatcher (idempotent): two worker
+        threads, each driving :meth:`step`, so that one batch's host
+        work runs while the other batch is on the device.  A worker
+        starts a dispatch beside the other's only on a full, healthy
+        bucket (:meth:`_select_locked`); otherwise it waits.  While
         running, submissions are served without the caller driving
         ``step()``, and new futures block in ``result()``."""
-        if self._thread is not None:
+        if self._threads:
             return self
         self._stop_evt.clear()
         self._loop_error = None
-        self._thread = threading.Thread(
-            target=self._dispatch_loop, name="polymul-engine-dispatch",
-            daemon=True,
-        )
-        self._thread.start()
+        self._threads = [
+            threading.Thread(
+                target=self._dispatch_loop,
+                name=f"polymul-engine-dispatch-{i}", daemon=True,
+            )
+            for i in range(self._WORKERS)
+        ]
+        for t in self._threads:
+            t.start()
         return self
 
     @property
     def running(self) -> bool:
-        return self._thread is not None
+        return bool(self._threads)
 
     def _dispatch_loop(self) -> None:
         try:
             while not self._stop_evt.is_set():
-                n = self.step()
-                if n == 0:
-                    with self._cond:
-                        if (not self._stop_evt.is_set()
-                                and not self._has_work_locked()):
-                            self._cond.wait(0.02)
+                if self.step():
+                    continue
+                with self._cond:  # idle or held: wait for a change
+                    pick = self._select_locked(time.perf_counter())
+                    if (not self._stop_evt.is_set()
+                            and (pick is None or pick[0] == "hold")):
+                        self._cond.wait(0.02)
         except BaseException as e:  # surfaced by run_until_idle/stop
-            self._loop_error = e
             with self._cond:
+                if self._loop_error is None:
+                    self._loop_error = e
                 self._cond.notify_all()
             raise
 
     def stop(self, *, drain: bool = True,
              timeout: float | None = None) -> None:
-        """Stop the dispatcher thread; with ``drain`` (default) first
+        """Stop the dispatcher's workers, joining both (within
+        ``timeout`` seconds in all); with ``drain`` (default) first
         wait until every queued request has resolved."""
-        if self._thread is None:
+        if not self._threads:
             return
         if drain:
             self.run_until_idle()
         self._stop_evt.set()
         with self._cond:
             self._cond.notify_all()
-        self._thread.join(timeout)
-        self._thread = None
+        t_end = None if timeout is None else time.perf_counter() + timeout
+        for t in self._threads:
+            t.join(None if t_end is None
+                   else max(t_end - time.perf_counter(), 0.0))
+        self._threads = []
         if self._loop_error is not None:
             err, self._loop_error = self._loop_error, None
             raise RuntimeError("engine dispatcher thread died") from err
@@ -1398,6 +1460,7 @@ class PolymulEngine:
             for c in self._m.values():
                 c.reset()
             self._m_packed_readbacks.reset()
+            self._m_overlapped.reset()
             for (engine, _), child in self._m_width_dispatches.children():
                 if engine == self.name:
                     child.reset()

@@ -12,13 +12,16 @@ according to a list of :class:`FaultRule` triggers driven by one seeded
 
 Design points:
 
-* **Deterministic.**  All randomness comes from ``seed``; the injector
-  counts executor calls itself, and the engine stamps every resolved
-  future with the ``dispatch_index`` of the call that produced it —
-  the two counters advance in lock-step (install the injector before
-  any dispatch), so the injector's ``log`` can be joined against
-  resolved futures after the fact.  That join is how the soak driver
-  *detects* injected corruption rather than merely surviving it.
+* **Deterministic.**  All randomness comes from ``seed``, one draw per
+  (rule, call) in the order calls enter the injector.  Installed on an
+  engine, the injector indexes each call by the engine's
+  ``dispatch_index`` for it (read from the calling thread), the index
+  the engine stamps on every future the call resolves; wrapped around
+  a bare function, it counts calls itself.  So the injector's ``log``
+  can be joined against resolved futures after the fact, also when the
+  engine's two dispatcher workers enter calls out of index order.  That
+  join is how the soak driver *detects* injected corruption rather than
+  merely surviving it.
 * **Raise beats corrupt.**  When several rules match one call, the
   first matching ``raise`` rule wins; otherwise every matching
   ``delay`` sleeps and every matching ``corrupt`` XORs the output.
@@ -43,6 +46,7 @@ Usage::
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 
 import numpy as np
@@ -121,9 +125,9 @@ class FaultInjector:
     Attributes
     ----------
     calls:
-        Executor calls seen so far; the current call's index is
-        ``calls - 1`` inside the wrapper and equals the engine's
-        ``dispatch_index`` stamp when installed before any dispatch.
+        Executor calls seen so far.  A call's index is the engine's
+        ``dispatch_index`` of it when installed on an engine, else the
+        count of calls before it.
     log:
         ``(call_index, kind, backend)`` for every fault fired — the
         ground truth the soak driver joins against resolved futures.
@@ -134,6 +138,8 @@ class FaultInjector:
         self.rng = np.random.default_rng(seed)
         self.calls = 0
         self.log: list[tuple[int, str, str]] = []
+        self._tls = None  # the engine's thread-local, once installed
+        self._lock = threading.Lock()  # calls may come from two threads
         self._counter = obs_metrics.registry().counter(
             "repro_faults_injected_total",
             "faults fired by the injection schedule",
@@ -165,37 +171,44 @@ class FaultInjector:
         schedule.  Pure pass-through once every rule is exhausted."""
 
         def _injected(pl, za, zb):
-            idx = self.calls
-            self.calls += 1
             backend = api.plan_key(pl).backend
-            hits = [
-                r for r in self.rules if self._matches(r, idx, backend)
-            ]
-            for r in hits:
-                if r.kind == "delay":
+            with self._lock:
+                idx = getattr(self._tls, "dispatch_index", None)
+                if idx is None:
+                    idx = self.calls
+                self.calls += 1
+                hits = [
+                    r for r in self.rules if self._matches(r, idx, backend)
+                ]
+                delays = [r for r in hits if r.kind == "delay"]
+                for r in delays:
                     self._fire(r, idx, backend)
-                    time.sleep(r.delay_s)
-            for r in hits:
-                if r.kind == "raise":
-                    self._fire(r, idx, backend)
-                    raise InjectedFault(
-                        f"injected transient fault at dispatch {idx} "
-                        f"(backend {backend!r})",
-                        dispatch_index=idx,
-                    )
+                raised = next((r for r in hits if r.kind == "raise"), None)
+                if raised is not None:
+                    self._fire(raised, idx, backend)
+            for r in delays:
+                time.sleep(r.delay_s)
+            if raised is not None:
+                raise InjectedFault(
+                    f"injected transient fault at dispatch {idx} "
+                    f"(backend {backend!r})",
+                    dispatch_index=idx,
+                )
             out = fn(pl, za, zb)
             for r in hits:
                 if r.kind == "corrupt":
-                    self._fire(r, idx, backend)
+                    with self._lock:
+                        self._fire(r, idx, backend)
                     out = np.asarray(out) ^ 1  # silent low-bit flip
             return out
 
         return _injected
 
     def install(self, engine) -> "FaultInjector":
-        """Wrap ``engine.executor`` in place.  Install before the first
-        dispatch so call indices align with the engine's
-        ``dispatch_index`` stamps."""
+        """Wrap ``engine.executor`` in place.  Each call is then indexed
+        by the engine's ``dispatch_index`` for it, which the engine
+        keeps on the calling thread's ``_tls`` while the call runs."""
+        self._tls = engine._tls
         engine.executor = self.wrap(engine.executor)
         return self
 

@@ -5,6 +5,9 @@ with plan tables resident per-shard), and the crypto partition rules.
 Mesh tests run on REAL 4-device host meshes — conftest.py forces
 ``--xla_force_host_platform_device_count=4`` before jax initializes.
 """
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -654,6 +657,228 @@ class TestFailureSemantics:
             assert np.array_equal(out, want)
         assert eng.stats["served"] == 10
         assert eng.pending() == 0
+
+
+class _Gate:
+    """Test-double wrapper of an engine executor: every call blocks
+    until ``release`` is set, and the calls in progress are counted."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.release = threading.Event()
+        self._cond = threading.Condition()
+        self.active = 0
+        self.peak = 0
+
+    def __call__(self, pl, za, zb):
+        with self._cond:
+            self.active += 1
+            self.peak = max(self.peak, self.active)
+            self._cond.notify_all()
+        try:
+            assert self.release.wait(60), "the gate never opened"
+            return self.inner(pl, za, zb)
+        finally:
+            with self._cond:
+                self.active -= 1
+
+    def wait_active(self, k: int) -> None:
+        with self._cond:
+            assert self._cond.wait_for(lambda: self.active >= k, 60), (
+                f"{self.active} executor calls in progress, waited for {k}"
+            )
+
+
+def _overlapped_total(eng) -> float:
+    return eng._registry.get("repro_engine_overlapped_dispatches_total").labels(
+        engine=eng.name
+    ).value
+
+
+def _assert_exact(pl, operands, outs):
+    for (a, b), out in zip(operands, outs):
+        want = np.asarray(api.polymul(pl, a[None], b[None]))[0]
+        assert np.array_equal(out, want)
+
+
+class TestTwoInFlight:
+    """Under ``start()`` two dispatcher workers keep up to two executor
+    calls outstanding, and a second only on a full, healthy bucket."""
+
+    def _gated(self, batch_slots=2, warm=True, **kw):
+        eng = PolymulEngine(batch_slots=batch_slots,
+                            registry=MetricsRegistry(), **kw)
+        pl = eng.plan(n=64, t=3, v=30)
+        if warm:
+            eng.warmup(pl)
+        gate = _Gate(eng.executor)
+        eng.executor = gate
+        return eng, pl, gate
+
+    def test_full_batches_run_two_at_once_bit_exact(self):
+        log = obs.SpanLog(None)
+        eng, pl, gate = self._gated(span_log=log)
+        rng = np.random.default_rng(16)
+        operands = [_rand_segments(pl, rng) for _ in range(8)]  # 4 batches
+        futs = [eng.submit(pl, a, b) for a, b in operands]
+        with eng:
+            gate.wait_active(2)
+            time.sleep(0.1)
+            assert gate.active == 2 and eng.snapshot()["inflight"] == 4
+            gate.release.set()
+            outs = [f.result(timeout=60) for f in futs]
+        assert gate.peak == 2
+        _assert_exact(pl, operands, outs)
+        steps = [s for s in log.spans() if s["name"] == "engine.step"]
+        assert len(steps) == eng.stats["dispatches"] == 4
+        overlapped = sum(s["attrs"]["overlapped"] for s in steps)
+        assert overlapped >= 1  # the second call began beside the first
+        assert _overlapped_total(eng) == overlapped
+        eng.reset_stats()
+        assert _overlapped_total(eng) == 0
+
+    def test_partial_batch_waits_behind_a_running_dispatch(self):
+        eng, pl, gate = self._gated(batch_slots=4)
+        rng = np.random.default_rng(17)
+        operands = [_rand_segments(pl, rng) for _ in range(4)]
+        with eng:
+            futs = [eng.submit(pl, *operands[0])]
+            gate.wait_active(1)
+            futs += [eng.submit(pl, a, b) for a, b in operands[1:]]
+            time.sleep(0.2)  # 3 < batch_slots queued: no second call
+            assert gate.active == 1 and eng.pending() == 3
+            gate.release.set()
+            outs = [f.result(timeout=60) for f in futs]
+        assert gate.peak == 1
+        assert eng.stats["dispatches"] == 2  # the three went as one batch
+        assert _overlapped_total(eng) == 0
+        _assert_exact(pl, operands, outs)
+
+    @pytest.mark.parametrize("state", ["degraded", "probing", "failing"])
+    def test_unhealthy_bucket_keeps_one_dispatch(self, state):
+        eng, pl, gate = self._gated(
+            breaker_cooldown_s=0.0 if state == "probing" else 60.0
+        )
+        rng = np.random.default_rng(18)
+        operands = [_rand_segments(pl, rng) for _ in range(6)]  # 3 batches
+        futs = [eng.submit(pl, a, b) for a, b in operands]
+        bucket = eng._buckets[api.plan_key(pl)]
+        if state == "failing":
+            bucket.failures = 1
+        else:  # a fallback of the same plan: nothing new to compile
+            bucket.chain.append(pl)
+            bucket.level = 1
+            bucket.opened_at = time.perf_counter()
+        with eng:
+            gate.wait_active(1)
+            time.sleep(0.2)
+            assert gate.active == 1 and eng.pending() == 4
+            gate.release.set()
+            outs = [f.result(timeout=60) for f in futs]
+        _assert_exact(pl, operands, outs)
+        snap = eng.snapshot()
+        assert snap["probes"] == (state == "probing")
+        if state == "degraded":  # stays degraded: one dispatch at a time
+            assert gate.peak == 1 and snap["degraded_buckets"] == 1
+            assert _overlapped_total(eng) == 0
+        else:  # the first dispatch restored the bucket's health
+            assert snap["degraded_buckets"] == 0 and bucket.failures == 0
+
+    def test_two_workers_that_miss_compile_once(self):
+        eng, pl, gate = self._gated(warm=False)
+        gate.release.set()
+        inner, compiles = eng._exec, []
+
+        class SlowCompile:  # widens the window in which both miss
+            def lower(self, *args):
+                lowered = inner.lower(*args)
+
+                class Lowered:
+                    def compile(self):
+                        compiles.append(1)
+                        time.sleep(0.3)
+                        return lowered.compile()
+
+                return Lowered()
+
+        eng._exec = SlowCompile()
+        rng = np.random.default_rng(19)
+        operands = [_rand_segments(pl, rng) for _ in range(8)]
+        futs = [eng.submit(pl, a, b) for a, b in operands]
+        with eng:
+            outs = [f.result(timeout=120) for f in futs]
+        assert len(compiles) == 1 and eng.trace_count == 1
+        _assert_exact(pl, operands, outs)
+
+    def test_stress_many_submitters_keep_the_counts(self):
+        """16 submitting threads against the two workers, with the
+        interpreter switching threads every 10 us: a lost update of
+        ``_inflight``, the dispatch index or a counter breaks a count."""
+        import sys
+
+        log = obs.SpanLog(None)
+        eng = PolymulEngine(batch_slots=4, registry=MetricsRegistry(),
+                            span_log=log)
+        pl = eng.plan(n=64, t=3, v=30)
+        eng.warmup(pl)
+        za, zb = _rand_segments(pl, np.random.default_rng(21), batch=128)
+        want = np.asarray(api.polymul(pl, za, zb))
+        futs = [None] * 128
+
+        def client(c):
+            for k in range(c, 128, 16):
+                futs[k] = eng.submit(pl, za[k], zb[k])
+                if k % 3 == 0:
+                    futs[k].result(timeout=60)
+
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            with eng:
+                clients = [threading.Thread(target=client, args=(c,))
+                           for c in range(16)]
+                for t in clients:
+                    t.start()
+                for t in clients:
+                    t.join(120)
+                outs = [f.result(timeout=60) for f in futs]
+        finally:
+            sys.setswitchinterval(was)
+        assert np.array_equal(np.stack(outs), want)
+        snap = eng.snapshot()
+        assert snap["served"] == snap["submitted"] == 128
+        assert snap["inflight"] == snap["queue_depth"] == 0
+        steps = [s for s in log.spans() if s["name"] == "engine.step"]
+        indices = sorted(s["attrs"]["dispatch_index"] for s in steps)
+        assert indices == list(range(snap["dispatches"]))
+        assert {f.dispatch_index for f in futs} == set(indices)
+        assert _overlapped_total(eng) == sum(
+            s["attrs"]["overlapped"] for s in steps
+        )
+
+    def test_stop_joins_both_workers(self):
+        eng = PolymulEngine(batch_slots=2, registry=MetricsRegistry())
+        for _ in range(2):  # and starts again after a stop
+            eng.start()
+            workers = list(eng._threads)
+            assert eng.running and len(workers) == 2
+            assert all(t.is_alive() for t in workers)
+            eng.stop()
+            assert not eng.running
+            assert not any(t.is_alive() for t in workers)
+
+    def test_step_without_start_runs_one_dispatch(self):
+        eng = PolymulEngine(batch_slots=2, registry=MetricsRegistry())
+        pl = eng.plan(n=64, t=3, v=30)
+        rng = np.random.default_rng(20)
+        operands = [_rand_segments(pl, rng) for _ in range(6)]
+        futs = [eng.submit(pl, a, b) for a, b in operands]
+        assert eng.step() == 2
+        assert eng.stats["dispatches"] == 1 and eng.pending() == 4
+        eng.run_until_idle()
+        assert eng.stats["dispatches"] == 3
+        assert _overlapped_total(eng) == 0
+        _assert_exact(pl, operands, [f.result() for f in futs])
 
 
 class TestEntryPoints:

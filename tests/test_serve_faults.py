@@ -104,11 +104,13 @@ class TestInjector:
         assert spot_check(pl, za2, zb2, fut2.result(), use_oracle=True)
 
 
-def _exactly_once_under_schedule(seed: int) -> None:
+def _exactly_once_under_schedule(seed: int, threaded: bool = False) -> None:
     """THE property: under an arbitrary seeded schedule of raises,
     delays, and corruptions, every submitted request resolves exactly
     once — a value or a typed EngineError, no losses, no duplicates —
-    and every un-corrupted result is bit-exact vs api.polymul."""
+    and every un-corrupted result is bit-exact vs api.polymul.
+    ``threaded`` serves through the background dispatcher, whose two
+    workers may enter executor calls out of dispatch-index order."""
     rng = np.random.default_rng(seed)
     eng = PolymulEngine(
         batch_slots=4, max_retries=8, breaker_threshold=2,
@@ -125,12 +127,15 @@ def _exactly_once_under_schedule(seed: int) -> None:
         ],
         seed=seed,
     ).install(eng)
+    if threaded:
+        eng.start()
     entries = []
     for i in range(24):
         pl = plans[i % 2]
         za, zb = _mk(pl, rng)
         entries.append((pl, za, zb, eng.submit(pl, za, zb)))
     eng.run_until_idle()
+    eng.stop()
 
     assert eng.pending() == 0
     s = eng.stats
@@ -156,6 +161,10 @@ class TestExactlyOnceProperty:
     @pytest.mark.parametrize("seed", [0, 1, 2])
     def test_exactly_once_seeded(self, seed):
         _exactly_once_under_schedule(seed)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_exactly_once_seeded_threaded(self, seed):
+        _exactly_once_under_schedule(seed, threaded=True)
 
     @given(seed=st.integers(min_value=0, max_value=2**20))
     @settings(max_examples=8, deadline=None)
