@@ -1,8 +1,9 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``repro.launch.serve_crypto`` and the
-other launchers, ``benchmarks.*``) call :func:`enable` once, before they
-compile anything; importing ``repro`` never touches the cache.
+Entry points (``chip_smoke.py``, ``bench/run.py``,
+``repro.launch.serve_crypto`` and the other launchers) call
+:func:`enable` once, before they compile anything; importing ``repro``
+never touches the cache.
 
 * If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
   nothing here sets another path.

@@ -271,9 +271,9 @@ def compose(residues: jnp.ndarray, plan: RnsPlan) -> jnp.ndarray:
 def compose_conventional(residues: jnp.ndarray, plan: RnsPlan) -> jnp.ndarray:
     """Baseline Fig 16(a): multiply residues by the full-width constants
     e_i = q_i^ * q_i~ mod q and reduce the sum mod q by subtraction.  Kept
-    as the comparison target for the Table V benchmark (the 'expensive'
-    variant differs in *datapath cost*, not in this functional model —
-    op-count accounting happens in benchmarks/postprocess.py)."""
+    as the Table V comparison target: the 'expensive' variant differs in
+    *datapath cost*, not in this functional model, and the tests hold
+    the two equal."""
     # e_i as limbs, wide enough for the un-reduced sum (< t * q * 2^v)
     Lw = max(plan.L, -(-(plan.q.bit_length() + plan.v + 8) // plan.w))
     e = [

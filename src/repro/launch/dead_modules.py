@@ -2,8 +2,8 @@
 public surface and the test suite.
 
 A stdlib-``ast`` import-graph walk (no imports are executed): roots are
-``repro/__init__.py``, every ``tests/test_*.py``, ``benchmarks/``,
-``examples/``, and the ``repro.launch`` CLIs (each is an entry point via
+``repro/__init__.py``, every ``tests/test_*.py``, ``examples/``, and
+the ``repro.launch`` CLIs (each is an entry point via
 ``python -m``).  Edges are ``import x`` / ``from x import y`` statements,
 including relative imports and the lazy ``_LAZY``-table indirection used
 by ``repro.analysis`` (string module paths in the module body are picked
@@ -100,7 +100,7 @@ def build_report(repo_root: Path) -> Dict[str, object]:
     # every launch CLI is a python -m entry point
     roots |= {m for m in known if m.startswith("repro.launch.")}
     external_roots: List[Path] = []
-    for pattern in ("tests/*.py", "benchmarks/*.py", "examples/*.py"):
+    for pattern in ("tests/*.py", "examples/*.py"):
         external_roots.extend(repo_root.glob(pattern))
     external_edges: Set[str] = set()
     for p in external_roots:

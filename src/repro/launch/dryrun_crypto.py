@@ -24,10 +24,6 @@ from repro.kernels import ops as ops_mod
 from repro.launch import analysis, hlo_analyzer
 from repro.launch.mesh import make_production_mesh
 
-ARTIFACTS = os.path.normpath(
-    os.path.join(os.path.dirname(__file__), "../../../benchmarks/artifacts")
-)
-
 
 def polymul_step(plan, za, zb):
     """segments (B, n, S) x2 -> product limbs (B, n, L).  The full paper
@@ -165,7 +161,7 @@ def main():
         "--row-blk", type=int, default=None,
         help="kernel tile rows per grid step (None = per-kernel default)",
     )
-    ap.add_argument("--out", default=ARTIFACTS)
+    ap.add_argument("--out", default="dryrun_artifacts")
     args = ap.parse_args()
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     fails = 0

@@ -35,9 +35,9 @@ gates rather than vibes:
   ``obs-smoke`` CI gate re-audits the written log through
   ``launch/obs_report.py --check``.
 
-A ``"serve_soak"`` record (shed rate, retries, breaker counts, p99,
-seed) merges into the BENCH_ci.json artifact next to the ``"serve"``
-record, along with an ``"obs"`` record (the metrics-registry dump);
+With ``--ci-smoke`` a ``"serve_soak"`` record (shed rate, retries,
+breaker counts, p99, seed) and an ``"obs"`` record (the
+metrics-registry dump) are written to the ``--out`` JSON artifact;
 ``--prom-out`` additionally writes the registry in Prometheus text
 format for the exporter-validity gate.
 """
@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -290,18 +289,15 @@ def run_soak(*, requests: int = 520, seed: int = 0, batch_slots: int = 8,
     return record
 
 
-def merge_record(out_path: str, record: dict) -> None:
-    """Merge the ``serve_soak`` record into the bench-smoke artifact
-    (same discipline as benchmarks/serve_throughput.py's ``serve``),
-    plus an ``obs`` record: the metrics-registry dump, so the artifact
-    carries the same numbers an exporter would scrape."""
-    doc = {}
-    if os.path.exists(out_path):
-        with open(out_path) as f:
-            doc = json.load(f)
-    doc["serve_soak"] = record
-    doc["obs"] = obs.to_json()
-    doc["failures"] = doc.get("failures", []) + record["failures"]
+def write_record(out_path: str, record: dict) -> None:
+    """Write the ``serve_soak`` record, plus an ``obs`` record: the
+    metrics-registry dump, so the artifact carries the same numbers an
+    exporter would scrape."""
+    doc = {
+        "serve_soak": record,
+        "obs": obs.to_json(),
+        "failures": record["failures"],
+    }
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1)
 
@@ -309,16 +305,16 @@ def merge_record(out_path: str, record: dict) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--ci-smoke", action="store_true",
-                    help="CI gate: 520 requests, merge BENCH record, "
-                         "exit non-zero on any contract violation")
+                    help="CI gate: 520 requests, write the --out "
+                         "record, exit non-zero on any contract violation")
     ap.add_argument("--requests", type=int, default=520)
     ap.add_argument("--seed", type=int, default=0,
                     help="seeds traffic, priorities, AND the fault "
                          "schedule; stamped into the output record")
     ap.add_argument("--slots", type=int, default=8)
-    ap.add_argument("--out", default="BENCH_ci.json",
-                    help="JSON artifact to merge the 'serve_soak' record "
-                         "into (--ci-smoke only)")
+    ap.add_argument("--out", default="SOAK_ci.json",
+                    help="JSON artifact for the 'serve_soak' record "
+                         "(--ci-smoke only)")
     ap.add_argument("--span-log", default=None, metavar="FILE",
                     help="trace every request into this JSONL span log "
                          "and audit span conservation as a gate")
@@ -335,7 +331,7 @@ def main(argv=None) -> int:
         with open(args.prom_out, "w") as f:
             f.write(obs.to_prometheus())
     if args.ci_smoke:
-        merge_record(args.out, record)
+        write_record(args.out, record)
     for msg in record["failures"]:
         print(f"[FAIL] {msg}", file=sys.stderr)
     return 1 if record["failures"] else 0

@@ -81,7 +81,7 @@ def to_prometheus(reg: MetricsRegistry | None = None) -> str:
 
 def to_json(reg: MetricsRegistry | None = None) -> dict[str, Any]:
     """Registry contents as one JSON-ready dict — the ``"obs"`` record
-    merged into ``BENCH_ci.json`` and the ``--json`` CLI output."""
+    in the soak's JSON artifact and the ``--json`` CLI output."""
     reg = reg if reg is not None else default_registry()
     families: list[dict[str, Any]] = []
     for m in reg.metrics():

@@ -82,9 +82,8 @@ Usage::
 
 Fault injection for soak testing wraps ``engine.executor``
 (:mod:`repro.serve.faults`); the soak driver is
-``launch/serve_soak.py`` and the throughput benchmark
-``benchmarks/serve_throughput.py`` (the ``serve-smoke`` /
-``serve-soak`` CI gates).
+``launch/serve_soak.py`` (the ``serve-soak`` CI gate).  Throughput is
+measured on the chip by ``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -446,7 +445,7 @@ def _result_dtype(cfg: api.PlanConfig):
     """The dtype the batch executable's result crosses to the host in:
     int32 while a base-2^w limb fits a signed 32-bit word (every width
     today: w = 28), else int64.  An int64 array reads back from a v5e
-    at ~0.2 GB/s, an int32 one 13–22x faster (PERF.md, Findings)."""
+    at ~0.2 GB/s, an int32 one 13–22x faster (PERF.md §6, Findings)."""
     return jnp.int32 if cfg.w <= 31 else jnp.int64
 
 
@@ -895,7 +894,7 @@ class PolymulEngine:
         the copy and the widening (the span's ``bytes`` are the device
         bytes read back).  Untraced it stays one call, which does
         the same in the runtime's C++ path: the split costs ~0.25 ms of
-        host time a dispatch on a v5e host (PERF.md, Findings)."""
+        host time a dispatch on a v5e host (PERF.md §6, Findings)."""
         cfg = api.plan_key(pl)
         if cfg.width == "oracle":
             # Host-only width: no tracing — runs the exact bigint path.

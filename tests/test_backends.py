@@ -51,6 +51,16 @@ class TestFusedBitExact:
         assert got == pm.oracle_multiply(a, b, p)
         assert got == pm.schoolbook_negacyclic(a, b, p.q)
 
+    @pytest.mark.parametrize("schedule", ["radix2", "four_step"])
+    @pytest.mark.parametrize("backend", ops.BACKENDS)
+    def test_every_backend_and_schedule_vs_oracle(self, backend, schedule):
+        """Every (backend, schedule) pair through the public plan at the
+        small preset is bit-exact vs the bigint oracle."""
+        p = params_mod.make_params(n=64, t=3, v=30)
+        a, b = _rand_ints(p, seed=7)
+        pl = repro.plan(n=64, t=3, v=30, backend=backend, schedule=schedule)
+        assert repro.polymul_ints(pl, a, b) == pm.oracle_multiply(a, b, p)
+
     @pytest.mark.parametrize("t,v,n", PRESETS)
     def test_backends_agree(self, t, v, n):
         p = params_mod.make_params(n=n, t=t, v=v)
@@ -138,8 +148,8 @@ class TestDispatch:
         )
 
     def test_hbm_traffic_model_ordering(self):
-        """The invariant the bench-smoke CI job enforces: each fusion
-        level strictly reduces modeled HBM bytes and kernel launches."""
+        """Each fusion level strictly reduces modeled HBM bytes and
+        kernel launches."""
         p = params_mod.make_params(n=64, t=3, v=30)
         models = {
             bk: ops.hbm_traffic_model(p, rows=4, backend=bk)
@@ -160,7 +170,7 @@ class TestDispatch:
     def test_traffic_model_matches_traced_launch_counts(self):
         """The model's kernel_launches must equal the number of
         pallas_call equations in the actual traced computation — the
-        structural tie that keeps the bench-smoke gate honest if a
+        structural tie that keeps the traffic model honest if a
         backend is ever de-fused."""
         p = params_mod.make_params(n=64, t=3, v=30)
         for bk in ops.BACKENDS:
@@ -173,6 +183,25 @@ class TestDispatch:
                 f"model claims {claimed}"
             )
         assert ops.count_pallas_launches(p, backend="pallas_fused_e2e") == 1
+
+    @pytest.mark.parametrize("backend,hbm_bytes,launches,intermediate", [
+        ("jnp", 47104, 0, 36864),
+        ("pallas", 59392, 10, 36864),
+        ("pallas_fused", 40960, 8, 18432),
+        ("pallas_fused_e2e", 10240, 1, 0),
+    ])
+    def test_small_preset_traffic_pinned(
+        self, backend, hbm_bytes, launches, intermediate
+    ):
+        """The modelled HBM bytes and the traced launches of the small
+        preset (n=64, t=3, v=30, two rows), pinned as literals: a change
+        that moves one of them updates the literal on purpose."""
+        p = params_mod.make_params(n=64, t=3, v=30)
+        m = ops.hbm_traffic_model(p, rows=2, backend=backend)
+        assert (
+            m["hbm_bytes"], m["kernel_launches"], m["intermediate_bytes"]
+        ) == (hbm_bytes, launches, intermediate)
+        assert ops.count_pallas_launches(p, backend=backend, rows=2) == launches
 
     @pytest.mark.parametrize("backend", ["pallas", "pallas_fused", "pallas_fused_e2e"])
     def test_arbitrary_leading_batch_dims(self, backend):

@@ -102,6 +102,39 @@ class TestNtt:
         want = pm.schoolbook_negacyclic(a.tolist(), b.tolist(), tb.q)
         assert np.asarray(got).tolist() == want
 
+    @pytest.mark.parametrize("n", [16, 64, 4096])
+    def test_output_is_bit_reversed_and_shuffle_is_redundant(self, n):
+        """The forward NTT's output is the natural-order evaluation in
+        bit-reversed order, so the explicitly shuffled cascade of Fig 17
+        (reorder to natural order, multiply, reorder back) equals the
+        no-permute cascade."""
+        import jax
+
+        tb = _tables(n)
+        brv = ntt_mod.bit_reverse_indices(n)
+        rng = np.random.default_rng(n + 2)
+        a = rng.integers(0, tb.q, size=(2, n))
+        b = rng.integers(0, tb.q, size=(2, n))
+
+        @jax.jit
+        def shuffled(a, b):
+            fa = ntt_mod.ntt(a, tb)[:, brv]
+            fb = ntt_mod.ntt(b, tb)[:, brv]
+            prod = ntt_mod.mul_mod(fa, fb, tb.q)
+            return fa, ntt_mod.intt(prod[:, np.argsort(brv)], tb)
+
+        fa, got = shuffled(jnp.asarray(a), jnp.asarray(b))
+        if n <= 64:
+            want = [
+                sum(int(x) * pow(tb.psi, (2 * k + 1) * j, tb.q)
+                    for j, x in enumerate(a[0])) % tb.q
+                for k in range(n)
+            ]
+            assert np.asarray(fa[0]).tolist() == want
+        direct = jax.jit(lambda a, b: ntt_mod.negacyclic_mul(a, b, tb))
+        want = direct(jnp.asarray(a), jnp.asarray(b))
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
     def test_no_permutation_between_stages(self):
         """The cascade lowers with zero gather/scatter/permute ops — the
         JAX-level expression of the no-shuffle contribution."""
@@ -246,6 +279,28 @@ class TestRns:
         ib = pm.limbs_out_to_ints(np.asarray(b), p.plan)
         assert ia == ib == xs
 
+    def test_sau_equals_generic_paper_preset(self):
+        """Table IV at the paper's preset (n=4096, t=6, v=30)."""
+        p = params_mod.make_params(n=4096, t=6, v=30)
+        rng = np.random.default_rng(0)
+        z = jnp.asarray(rng.integers(0, 1 << 30, size=(4096, p.plan.seg_count)))
+        a = np.asarray(rns_mod.decompose(z, p.plan))
+        b = np.asarray(rns_mod.decompose_sau(z, p.plan))
+        assert np.array_equal(a, b)
+
+    def test_conventional_equals_optimized_paper_preset(self):
+        """Table V at the paper's preset (n=4096, t=6, v=30)."""
+        p = params_mod.make_params(n=4096, t=6, v=30)
+        rng = random.Random(1)
+        xs = [rng.randrange(p.q) for _ in range(4096)]
+        res = jnp.asarray(
+            np.array([[x % int(q) for x in xs] for q in p.plan.qs])
+        )
+        a = rns_mod.compose(res, p.plan)
+        b = rns_mod.compose_conventional(res, p.plan)
+        assert pm.limbs_out_to_ints(np.asarray(a), p.plan) == xs
+        assert pm.limbs_out_to_ints(np.asarray(b), p.plan) == xs
+
     @given(st.integers(0, 2**89))
     @settings(max_examples=40, deadline=None)
     def test_decompose_property(self, x):
@@ -352,6 +407,15 @@ class TestSchedule:
         # paper: shuffling increases latency by ~20.0%
         inc = sched.latency_cycles(4096, with_shuffle=True) / sched.latency_cycles(4096)
         assert abs(inc - 1.25 * 0.8 - 0.2) < 0.06 or abs(inc - 1.2) < 0.06
+
+    def test_table_vii_latency_and_roy_ratio(self):
+        # §V-D: n=4096 with the 152-cycle pipeline at 240 MHz is the
+        # paper's 4246-4254 cycles (17.4-17.7 us), 49.2x below Roy [7]'s
+        # normalized 196,003 cycles at 225 MHz
+        lat = sched.latency_cycles(4096, t_pipe=152)
+        assert 4246 <= lat <= 4254
+        assert 17.4 <= lat / 240 <= 17.7
+        assert round((196_003 / 225) / (lat / 240), 1) == 49.2
 
     def test_folding_tables_match_paper_16pt(self):
         # Eq (1): NTT folding sets for n=16

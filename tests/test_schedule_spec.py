@@ -27,6 +27,7 @@ from repro.core import params as params_mod
 from repro.core import polymul as pm
 from repro.core import primes as primes_mod
 from repro.core import schedule as sched
+from repro.kernels import ops
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -275,3 +276,19 @@ class TestBigNAcceptance:
         a = [rng.randrange(p.q) for _ in range(n)]
         b = [rng.randrange(p.q) for _ in range(n)]
         assert repro.polymul_ints(pl, a, b) == pm.oracle_multiply(a, b, p)
+
+    def test_n4096_fused_e2e_spec_pinned(self):
+        """The n=4096 fused-e2e point's frozen spec and modelled traffic,
+        pinned as literals: its tile fits the VMEM budget."""
+        pl = repro.plan(
+            n=4096, t=2, v=30, backend="pallas_fused_e2e",
+            schedule="four_step",
+        )
+        spec = pl.config.schedule
+        assert str(spec) == "four_step[32.128]"
+        assert (spec.depth, spec.row_blk, spec.tile_bytes) == (1, 4, 1179648)
+        assert spec.tile_bytes <= spec.vmem_budget == 16 << 20
+        m = ops.hbm_traffic_model(
+            pl.params, rows=1, backend="pallas_fused_e2e"
+        )
+        assert (m["hbm_bytes"], m["kernel_launches"]) == (229376, 1)

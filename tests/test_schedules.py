@@ -209,7 +209,7 @@ class TestE2eKernelVariants:
 
 class TestCostModel:
     """The lane-alignment / reduction-op claims, cross-checked against
-    the traced kernels (the bench-smoke discipline, in-tree)."""
+    the traced kernels."""
 
     @pytest.mark.parametrize("t,v,n", PRESETS)
     @pytest.mark.parametrize("schedule", ["radix2", "four_step"])
@@ -228,6 +228,29 @@ class TestCostModel:
         m = ops.transform_cost_model(p, schedule="four_step")
         assert m["sublane_stages"] == 0
         assert ops.transform_cost_model(p, schedule="radix2")["sublane_stages"] == 7
+
+    @pytest.mark.parametrize("t,v,n,schedule,sublane,reduction_ops,strict", [
+        (3, 30, 64, "radix2", 6, 13, 30),
+        (3, 30, 64, "four_step", 0, 13, 30),
+        (6, 30, 256, "radix2", 7, 17, 40),
+        (6, 30, 256, "four_step", 0, 17, 40),
+    ])
+    def test_cost_model_pinned(
+        self, t, v, n, schedule, sublane, reduction_ops, strict
+    ):
+        """Sub-128-lane stages and reduction ops per transform, pinned as
+        literals in both directions (the traced selects are tied to the
+        model above): lazy reduction stays >= 2x below strict."""
+        p = params_mod.make_params(n=n, t=t, v=v)
+        for direction in ("fwd", "inv"):
+            m = ops.transform_cost_model(
+                p, schedule=schedule, direction=direction
+            )
+            assert (
+                m["sublane_stages"], m["lazy_window"], m["reduction_ops"],
+                m["strict_reduction_ops"],
+            ) == (sublane, 2, reduction_ops, strict)
+        assert 2 * reduction_ops <= strict
 
     def test_lazy_saves_2x_reduction_ops(self):
         p = params_mod.make_params(n=256, t=6, v=30)
